@@ -63,22 +63,11 @@ sys.path.insert(0, "src")
 
 import repro.calculators  # noqa: F401,E402
 from repro.configs import get_config  # noqa: E402
-from repro.launch.mesh import make_serving_mesh  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import (make_serving_mesh,  # noqa: E402
+                               simulated_devices_env)
 from repro.serving import (AsyncFrontend, GraphServer, LLMEngine,  # noqa: E402
                            Policy)
-
-
-def _forced_device_env(n: int) -> dict:
-    """Environment for a re-exec with ``n`` forced host devices — the
-    XLA flag must be set before the jax backend initializes, which in
-    this (already-initialized) process is too late."""
-    env = dict(os.environ)
-    flags = [t for t in env.get("XLA_FLAGS", "").split()
-             if not t.startswith("--xla_force_host_platform_device_count")]
-    flags.append(f"--xla_force_host_platform_device_count={n}")
-    env["XLA_FLAGS"] = " ".join(flags)
-    env["JAX_PLATFORMS"] = "cpu"
-    return env
 
 
 def percentile(xs, q):
@@ -291,8 +280,9 @@ def main(argv=None) -> int:
                          "QPS is under this bound")
     ap.add_argument("--mesh", type=int, default=0,
                     help="serve over an N-way tensor-parallel mesh "
-                         "(docs/SHARDING.md); re-execs with forced host "
-                         "devices when the process has fewer than N")
+                         "(docs/SHARDING.md); a CPU run with fewer than "
+                         "N devices re-execs with forced host devices, "
+                         "an accelerator run with fewer fails")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="BENCH_serve.json")
     ap.add_argument("--smoke", action="store_true",
@@ -300,11 +290,14 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
+    enable_compile_cache()
     if args.mesh > 1 and jax.device_count() < args.mesh:
+        # a CPU run re-execs with N simulated host devices (the flag
+        # must precede backend start-up); an accelerator run fails here
+        env = simulated_devices_env(args.mesh)
         cmd = [sys.executable, os.path.abspath(__file__)] + \
             list(sys.argv[1:] if argv is None else argv)
-        return subprocess.run(cmd,
-                              env=_forced_device_env(args.mesh)).returncode
+        return subprocess.run(cmd, env=env).returncode
     if args.smoke:
         args.requests = min(args.requests, 6)
         args.max_new_tokens = min(args.max_new_tokens, 8)

@@ -270,17 +270,21 @@ def bench_kernels() -> None:
             jax.block_until_ready(fn(*args))
         return (time.perf_counter() - t0) / reps * 1e6
 
+    # the kernels run interpreted on the CPU (repro.kernels.ops)
+    mode = "interpret" if jax.default_backend() == "cpu" else "compiled"
     t_kern = timeit(flash_attention, q, k, v)
     t_ref = timeit(jax.jit(flash_attention_ref), q, k, v)
-    emit("flash_attention_interpret", t_kern,
-         f"oracle {t_ref:.0f}us (interpret mode; perf meaningful on TPU)")
+    emit(f"flash_attention_{mode}", t_kern,
+         f"oracle {t_ref:.0f}us ({jax.devices()[0].device_kind})")
     x = jax.random.normal(key, (512, 1024), jnp.float32)
     s = jnp.ones((1024,), jnp.float32)
-    emit("rmsnorm_interpret", timeit(rmsnorm, x, s),
+    emit(f"rmsnorm_{mode}", timeit(rmsnorm, x, s),
          f"oracle {timeit(jax.jit(rmsnorm_ref), x, s):.0f}us")
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for bench in (bench_scheduler_pipelining, bench_sync_policy_overhead,
                   bench_flow_limiter, bench_tracer_overhead,

@@ -85,6 +85,8 @@ sys.path.insert(0, "src")
 
 import repro.calculators  # noqa: F401,E402
 from repro.configs import get_config  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import simulated_devices_env  # noqa: E402
 from repro.serving import (GraphServer, HybridBackend,  # noqa: E402
                            LLMEngine, PagedBackend, Scheduler,
                            SlotBackend, StateBackend)
@@ -833,43 +835,40 @@ def bench_roofline(args, report):
           f"{verify_out['gather']['ms_per_step']}ms -> fused "
           f"{verify_out['fused']['ms_per_step']}ms; suffix attention "
           f"flash {flash_ms:.2f}ms vs chunked XLA {chunk_ms:.2f}ms")
-    from repro.kernels.ops import INTERPRET
-    armed = not args.smoke and not INTERPRET
+    import jax
+    # kernels interpret on the CPU (repro.kernels.ops), where the ratio
+    # prices the interpreter, not HBM traffic
+    armed = not args.smoke and jax.default_backend() != "cpu"
     section["speedup_gate_armed"] = armed
     return exact, speedup >= 1.15, armed
-
-
-def _forced_device_env(n: int) -> dict:
-    """Copy of the environment with XLA forced to ``n`` simulated host
-    devices (any prior forced count replaced) — how the scaling probes
-    and ``--mesh N`` re-exec get a CPU 'pod' (docs/SHARDING.md)."""
-    env = dict(os.environ)
-    keep = [t for t in env.get("XLA_FLAGS", "").split()
-            if not t.startswith("--xla_force_host_platform_device_count")]
-    keep.append(f"--xla_force_host_platform_device_count={n}")
-    env["XLA_FLAGS"] = " ".join(keep)
-    env["JAX_PLATFORMS"] = "cpu"
-    return env
 
 
 def scaling_probe(args) -> int:
     """Hidden ``--scaling-probe N`` entry point: one mesh-size
     measurement for the ``scaling`` section, run in a subprocess whose
-    XLA_FLAGS force N host devices.  Serves a FIXED workload (same
-    prompts, seed and greedy decode at every mesh size, so the parent
-    can require bit-identical outputs) through a paged GraphServer on an
-    N-way tensor-parallel mesh, with 2 scheduler slots per rank — the
-    concurrency each rank's share of the arena adds at fixed per-rank
-    memory.  Prints one ``SCALING {json}`` line for the parent."""
+    XLA_FLAGS force N host devices (CPU runs only).  Prints one
+    ``SCALING {json}`` line for the parent."""
     import jax
-    from repro.launch.mesh import make_serving_mesh, mesh_desc
-    from repro.serving.kvcache.backend import max_request_tokens
-
     n = int(args.scaling_probe)
     if jax.device_count() < n:
         print(f"SCALING-ERROR need {n} devices, "
               f"have {jax.device_count()}")
         return 1
+    print("SCALING " + json.dumps(probe_mesh(args, n), sort_keys=True))
+    return 0
+
+
+def probe_mesh(args, n: int) -> dict:
+    """One mesh-size measurement for the ``scaling`` section.  Serves a
+    FIXED workload (same prompts, seed and greedy decode at every mesh
+    size, so the caller can require bit-identical outputs) through a
+    paged GraphServer on an N-way tensor-parallel mesh over the first
+    ``n`` devices, with 2 scheduler slots per rank — the concurrency
+    each rank's share of the arena adds at fixed per-rank memory."""
+    import jax
+    from repro.launch.mesh import make_serving_mesh, mesh_desc
+    from repro.serving.kvcache.backend import max_request_tokens
+
     cfg = get_config(args.arch).reduced()
     # head counts divisible by every probed mesh size, so the KV arena
     # shards on the kv_heads axis at tp in {1, 2, 4, 8} and the fused
@@ -921,16 +920,38 @@ def scaling_probe(args) -> int:
         "outputs": outs,
     }
     srv.close()
-    print("SCALING " + json.dumps(doc, sort_keys=True))
-    return 0
+    return doc
+
+
+def _scaling_subprocess(args, n: int):
+    """``probe_mesh`` at size ``n`` in a child with ``n`` simulated host
+    devices (CPU runs); None when the child fails."""
+    cmd = [sys.executable, os.path.abspath(__file__), "--scaling-probe",
+           str(n), "--seed", str(args.seed), "--arch", args.arch]
+    if args.smoke:
+        cmd.append("--smoke")
+    if args.fused:
+        cmd.append("--fused")
+    proc = subprocess.run(cmd, capture_output=True, text=True,
+                          env=simulated_devices_env(n), timeout=600)
+    line = next((ln for ln in proc.stdout.splitlines()
+                 if ln.startswith("SCALING ")), None)
+    if proc.returncode != 0 or line is None:
+        print(f"scaling probe mesh={n} failed "
+              f"(rc={proc.returncode}):\n{proc.stdout[-2000:]}\n"
+              f"{proc.stderr[-2000:]}")
+        return None
+    return json.loads(line[len("SCALING "):])
 
 
 def bench_scaling(args, report) -> dict:
     """Tensor-parallel scaling curve (docs/SHARDING.md): re-run one
-    fixed workload at mesh sizes 1/2/4/8 (1/2 in smoke), each in a
-    subprocess whose XLA_FLAGS force that many simulated host devices
-    (the forced count must be set before the jax backend initializes,
-    which is why these cannot run in-process).  Gates:
+    fixed workload at mesh sizes 1/2/4/8 (1/2 in smoke).  On the CPU
+    each size runs in a subprocess whose XLA_FLAGS force that many
+    simulated host devices (the forced count must be set before the jax
+    backend initializes).  On an accelerator the sizes the host has run
+    in this process over device subsets: a child could not reach chips
+    this process holds.  Gates:
 
     * every probe's outputs are bit-identical to the mesh=1 run
       (always enforced — sharding must not change a single token);
@@ -941,27 +962,13 @@ def bench_scaling(args, report) -> dict:
       smoke shapes are overhead-bound and simulated devices share one
       CPU's cores, so the smoke job just reports the curve).
     """
+    import jax
     sizes = [1, 2] if args.smoke else [1, 2, 4, 8]
-    script = os.path.abspath(__file__)
-    probes = {}
-    for n in sizes:
-        cmd = [sys.executable, script, "--scaling-probe", str(n),
-               "--seed", str(args.seed), "--arch", args.arch]
-        if args.smoke:
-            cmd.append("--smoke")
-        if args.fused:
-            cmd.append("--fused")
-        proc = subprocess.run(cmd, capture_output=True, text=True,
-                              env=_forced_device_env(n), timeout=600)
-        line = next((ln for ln in proc.stdout.splitlines()
-                     if ln.startswith("SCALING ")), None)
-        if proc.returncode != 0 or line is None:
-            print(f"scaling probe mesh={n} failed "
-                  f"(rc={proc.returncode}):\n{proc.stdout[-2000:]}\n"
-                  f"{proc.stderr[-2000:]}")
-            probes[n] = None
-            continue
-        probes[n] = json.loads(line[len("SCALING "):])
+    if jax.default_backend() != "cpu":
+        sizes = [n for n in sizes if n <= jax.device_count()]
+        probes = {n: probe_mesh(args, n) for n in sizes}
+    else:
+        probes = {n: _scaling_subprocess(args, n) for n in sizes}
     ran = [n for n in sizes if probes.get(n) is not None]
     base = probes.get(1)
     identical = (base is not None and len(ran) == len(sizes) and all(
@@ -1026,16 +1033,17 @@ def main(argv=None) -> int:
                          "entry point is --smoke --fused)")
     ap.add_argument("--mesh", type=int, default=0,
                     help="serve the whole suite on an N-way tensor-"
-                         "parallel mesh (docs/SHARDING.md); when fewer "
-                         "devices exist the run re-execs itself with "
-                         "XLA_FLAGS forcing N simulated host devices "
-                         "(the CI sharded-smoke entry point is "
-                         "--smoke --mesh 2)")
+                         "parallel mesh (docs/SHARDING.md); a CPU run "
+                         "with fewer devices re-execs itself with "
+                         "XLA_FLAGS forcing N simulated host devices, "
+                         "an accelerator run with fewer fails (the CI "
+                         "sharded-smoke entry point is --smoke --mesh 2)")
     ap.add_argument("--scaling-probe", type=int, default=0,
                     help=argparse.SUPPRESS)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny config for the CI smoke job")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.scaling_probe:
         return scaling_probe(args)
     if args.mesh > 1:
@@ -1043,13 +1051,13 @@ def main(argv=None) -> int:
         if jax.device_count() < args.mesh:
             # XLA_FLAGS must be set before the backend initializes —
             # too late in this process, so re-exec with the forced count
+            env = simulated_devices_env(args.mesh)
             print(f"--mesh {args.mesh} needs {args.mesh} devices, have "
                   f"{jax.device_count()}; re-running with "
                   f"--xla_force_host_platform_device_count={args.mesh}")
             cmd = [sys.executable, os.path.abspath(__file__)] + \
                 list(sys.argv[1:] if argv is None else argv)
-            return subprocess.run(
-                cmd, env=_forced_device_env(args.mesh)).returncode
+            return subprocess.run(cmd, env=env).returncode
     if args.smoke:
         args.requests = min(args.requests, 6)
         args.max_new_tokens = min(args.max_new_tokens, 8)

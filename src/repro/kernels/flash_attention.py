@@ -1,19 +1,22 @@
 """Flash attention as a Pallas TPU kernel.
 
-TPU adaptation of the blockwise online-softmax algorithm (DESIGN.md §6):
+TPU adaptation of the blockwise online-softmax algorithm:
 
 * grid = (batch, q_heads, num_q_blocks, num_kv_blocks); the LAST grid axis
   iterates innermost and sequentially on TPU, so the (m, l, acc) running
   statistics live in VMEM scratch carried across kv blocks;
-* BlockSpecs tile Q/K/V/O into VMEM with MXU-aligned tiles (block sizes are
-  multiples of 128 in the lane dim; head_dim is the minor axis);
+* the kernel runs on a head-major ``[B, H, S, hd]`` view (the wrapper
+  transposes), so every block is a ``(block, hd)`` tile whose last two
+  dims meet Mosaic's (8, 128)-or-full-extent rule; a ``[B, S, H, hd]``
+  block would put a 1 against ``H`` in the second-minor dim;
 * GQA is expressed in the K/V index_map (query head h reads kv head
   h // group_size) — no repeated KV in HBM;
 * causal/windowed masking is computed from block indices; fully-masked kv
   blocks write nothing and skip the matmuls via ``pl.when``.
 
 Validated against ``ref.flash_attention_ref`` in interpret mode (CPU);
-on real TPU hardware the same code lowers via Mosaic.
+on a TPU the same code lowers through Mosaic (tests/test_tpu_compile.py
+compiles it for a v5e chip).
 """
 from __future__ import annotations
 
@@ -52,9 +55,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)        # [bq, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)        # [bk, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)        # [bk, hd]
+        q = q_ref[0, 0].astype(jnp.float32)              # [bq, hd]
+        k = k_ref[0, 0].astype(jnp.float32)              # [bk, hd]
+        v = v_ref[0, 0].astype(jnp.float32)              # [bk, hd]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * scale                                     # [bq, bk]
@@ -68,12 +71,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         if window:
             valid = valid & (k_pos > q_pos - window)
         s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                               # [bq, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
-        acc_scr[...] = (acc_scr[...] * corr[:, None]
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = (acc_scr[...] * corr
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -82,7 +85,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ik == nk - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -104,28 +107,27 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
     query row's accumulation order — and hence its output bits —
     independent of ``T``, ``q_offset``, and the q-block grouping.  That
     is the chunk-invariance argument for routing chunked prefill's
-    suffix attention through this kernel (docs/KERNELS.md)."""
+    suffix attention through this kernel (docs/KERNELS.md).  The query
+    side is likewise padded up to a fixed ``block_q`` multiple, so every
+    q block runs the same ``[block_q, block_k]`` matmul shape whatever
+    ``S`` is."""
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
-    block_q = min(block_q, S)
     pad_q = (-S) % block_q
     pad_k = (-T) % block_k
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-    if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
+    # head-major view: [B, H, S, hd] blocks are (block, hd) tiles
+    q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
     Sp, Tp = S + pad_q, T + pad_k
     nq, nk = Sp // block_q, Tp // block_k
     grid = (B, H, nq, nk)
 
-    q_spec = pl.BlockSpec((1, block_q, 1, hd),
-                          lambda b, h, iq, ik: (b, iq, h, 0))
-    kv_spec = pl.BlockSpec((1, block_k, 1, hd),
-                           lambda b, h, iq, ik: (b, ik, h // G, 0))
-    o_spec = pl.BlockSpec((1, block_q, 1, hd),
-                          lambda b, h, iq, ik: (b, iq, h, 0))
+    q_spec = pl.BlockSpec((1, 1, block_q, hd),
+                          lambda b, h, iq, ik: (b, h, iq, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_k, hd),
+                           lambda b, h, iq, ik: (b, h // G, ik, 0))
 
     kernel = functools.partial(
         _flash_kernel, causal=causal, window=window, block_q=block_q,
@@ -137,13 +139,13 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
         kernel,
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sp, H, hd), q.dtype),
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Sp, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),   # running max m
-            pltpu.VMEM((block_q,), jnp.float32),   # normalizer l
+            pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
+            pltpu.VMEM((block_q, 1), jnp.float32),   # normalizer l
             pltpu.VMEM((block_q, hd), jnp.float32),  # accumulator
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :S]
+    return out.transpose(0, 2, 1, 3)[:, :S]

@@ -55,6 +55,28 @@ from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
 
+#: Scoped-VMEM cap for the fully-gathered variant.  It stages the whole
+#: row (two ``[T, KV, hd]`` scratches, lane-padded, plus their f32 views)
+#: in VMEM: at MiniCPM-2B widths with T=2048 that is over 100 MiB, far
+#: past Mosaic's 16 MiB default.  A v5e core has 128 MiB of VMEM; rows
+#: that do not fit under this cap belong to the split-K variant.
+GATHER_VMEM_LIMIT = 124 << 20
+#: Mosaic's VMEM need of the gathered variant per byte of one tile-padded
+#: ``[T, KV, hd]`` row: the two row scratches, their f32 working copies
+#: and the register spill they compile to.  Compiled for a described v5e
+#: at 36 KV heads x 64 it needed 107 MiB at T=2048 and 213 MiB at
+#: T=4096: 5.3 row bytes each time.
+GATHER_VMEM_PER_ROW_BYTE = 5.33
+
+
+def gathered_vmem_bytes(rows: int, kv_heads: int, head_dim: int,
+                        itemsize: int = 2) -> int:
+    """Estimated scoped VMEM the gathered variant needs for ``rows``
+    cached positions of ``kv_heads`` x ``head_dim`` (one shard's heads
+    under tensor parallelism); compare with ``GATHER_VMEM_LIMIT``."""
+    row = -(-kv_heads // 8) * 8 * (-(-head_dim // 128) * 128) * itemsize
+    return int(GATHER_VMEM_PER_ROW_BYTE * rows * row)
+
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def rope_freqs(hd: int, theta: float) -> jax.Array:
@@ -294,6 +316,8 @@ def fused_flash_decode_kernel(q: jax.Array, k_new: jax.Array,
         # positions(1), q(2), k_new(3), v_new(4), k_pages(5), v_pages(6),
         # freqs(7)
         input_output_aliases={5: 1, 6: 2},
+        compiler_params=(None if split_k else pltpu.CompilerParams(
+            vmem_limit_bytes=GATHER_VMEM_LIMIT)),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(positions, jnp.int32), q, k_new, v_new, k_pages, v_pages,

@@ -1,38 +1,39 @@
+"""Production-mesh dry run: lower and compile every (arch, input shape,
+mesh) combination against 512 placeholder host devices and record
+memory, cost and roofline estimates.
+
+    python -m repro.launch.dryrun --arch minicpm_2b
+"""
+import argparse
+import dataclasses
+import json
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import: jax locks the device
-# count at first initialization, and the production-mesh dry-run needs 512
-# placeholder host devices to build the 16x16 / 2x16x16 meshes.
+import sys
+import time
+from typing import Any, Dict, Optional
 
-import argparse          # noqa: E402
-import dataclasses       # noqa: E402
-import json              # noqa: E402
-import sys               # noqa: E402
-import time              # noqa: E402
-from typing import Any, Dict, Optional  # noqa: E402
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-import jax               # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-from ..configs import ALL_ARCHS, get_config                    # noqa: E402
-from ..models import INPUT_SHAPES, Model                       # noqa: E402
-from ..models.transformer import RuntimeFlags                  # noqa: E402
-from ..optim import make_optimizer, make_schedule              # noqa: E402
+from ..configs import ALL_ARCHS, get_config
+from ..models import INPUT_SHAPES, Model
+from ..models.transformer import RuntimeFlags
+from ..optim import make_optimizer, make_schedule
 from ..runtime.steps import TrainState, make_decode_step, \
-    make_prefill_step, make_train_step                         # noqa: E402
+    make_prefill_step, make_train_step
 from ..sharding.rules import (batch_specs, cache_specs, param_specs,
-                              train_state_specs)               # noqa: E402
+                              train_state_specs)
 from .analysis import (collective_bytes, cost_stats, memory_stats,
-                       model_flops, roofline)                  # noqa: E402
-from .hlo_cost import hlo_cost                                 # noqa: E402
-from .mesh import make_production_mesh, mesh_context          # noqa: E402
+                       model_flops, roofline)
+from .hlo_cost import hlo_cost
+from .mesh import make_production_mesh
 
 LONG_WINDOW = 8192
 
 
 def adjusted_config(cfg, shape_name: str):
-    """long_500k policy (DESIGN.md §4): pure-attention archs run the
+    """long_500k policy: pure-attention archs run the
     sliding-window variant; SSM/hybrid run natively."""
     if shape_name == "long_500k" and not cfg.sub_quadratic \
             and cfg.family != "hybrid":
@@ -110,7 +111,7 @@ def dryrun_one(arch: str, shape_name: str, multi_pod: bool,
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         fn, args = build_lowering(arch, shape_name, mesh, flags)
         lowered = fn.lower(*args)
         compiled = lowered.compile()
@@ -208,4 +209,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # before the backend initializes: the production meshes (16x16 and
+    # 2x16x16) need 512 placeholder host devices
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     sys.exit(main())

@@ -8,7 +8,10 @@ Hardware constants for the roofline (per chip): 197 TFLOP/s bf16,
 """
 from __future__ import annotations
 
+import os
+
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e roofline constants (per chip)
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s
@@ -16,17 +19,24 @@ HBM_BW = 819e9                    # bytes/s
 ICI_BW = 50e9                     # bytes/s per link
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the sharding rules place params
+    and activations by annotation and let GSPMD propagate the rest,
+    which Explicit axes (``make_mesh``'s default) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Small mesh over whatever devices exist (CPU smoke tests)."""
     n = len(jax.devices())
     mp = model_parallel if n % model_parallel == 0 else 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return _auto_mesh((n // mp, mp), ("data", "model"))
 
 
 def make_serving_mesh(model_parallel: int = 0, *, devices=None):
@@ -65,9 +75,23 @@ def mesh_desc(mesh) -> dict:
     return {"devices": n, "axes": axes, "platform": ",".join(plats)}
 
 
-def mesh_context(mesh):
-    """Ambient-mesh context manager across jax versions: ``jax.set_mesh``
-    where it exists (>= 0.5), else the Mesh object itself (0.4.x Meshes are
-    context managers with the same ambient-mesh effect)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+def simulated_devices_env(n: int) -> dict:
+    """Copy of the environment that gives a child process ``n`` simulated
+    host devices (any earlier forced count replaced) — how a CPU run
+    re-execs itself to get a multi-device 'pod' (docs/SHARDING.md).
+
+    Only a CPU run may do this.  On an accelerator the parent already
+    holds the chips, and a child that needs them would fail or hang, so
+    this raises instead: a mesh there is built from the real devices in
+    one process."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{n} devices wanted, {jax.device_count()} {backend} devices "
+            f"present; only a CPU run can simulate more")
+    env = dict(os.environ)
+    flags = [t for t in env.get("XLA_FLAGS", "").split()
+             if not t.startswith("--xla_force_host_platform_device_count")]
+    flags.append(f"--xla_force_host_platform_device_count={n}")
+    env["XLA_FLAGS"] = " ".join(flags)
+    return env

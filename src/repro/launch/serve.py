@@ -6,6 +6,9 @@ docs/FRONTEND.md), or the original fixed-batch flow-limited graph
     python -m repro.launch.serve --arch qwen3_32b --reduced \
         --requests 32 --clients 8
 
+    python -m repro.launch.serve --arch minicpm_2b --no-reduced --paged \
+        --max-len 2048 --num-blocks 265 --max-new-tokens 32
+
     python -m repro.launch.serve --frontend async --ttft-ms 500 \
         --cancel-frac 0.25 --retries 1
 """
@@ -24,6 +27,7 @@ from ..core import Graph
 from ..serving import (AsyncFrontend, GraphServer, LLMEngine, Policy,
                        build_serving_graph)
 from .. import calculators  # noqa: F401 - registers basics
+from .compile_cache import enable_compile_cache
 
 
 def _make_prompts(rng, n, vocab):
@@ -236,7 +240,12 @@ def run_fixed_batch(args, cfg, engine) -> int:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minicpm_2b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the arch's reduced CPU preset (default); "
+                         "--no-reduced serves its published widths")
+    ap.add_argument("--max-len", type=int, default=128,
+                    help="engine context length: prompt + new tokens")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--clients", type=int, default=4)
     ap.add_argument("--num-slots", type=int, default=4)
@@ -307,10 +316,11 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    engine = LLMEngine(cfg, max_len=128, seed=args.seed)
+    engine = LLMEngine(cfg, max_len=args.max_len, seed=args.seed)
     if args.fixed_batch:
         return run_fixed_batch(args, cfg, engine)
     if args.frontend == "async":

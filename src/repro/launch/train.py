@@ -28,7 +28,8 @@ from ..models.transformer import RuntimeFlags
 from ..optim import make_schedule
 from ..runtime.steps import TrainState, make_train_step
 from ..sharding.rules import batch_specs, param_specs, train_state_specs
-from .mesh import make_host_mesh, make_production_mesh, mesh_context
+from .compile_cache import enable_compile_cache
+from .mesh import make_host_mesh, make_production_mesh
 
 
 def main(argv=None) -> int:
@@ -47,6 +48,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -76,7 +78,7 @@ def main(argv=None) -> int:
     state = init_state(params)
 
     state_sh = train_state_specs(model.template, mesh, cfg.optimizer)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(state, state_sh)
         step_fn = jax.jit(train_step, in_shardings=(state_sh, None),
                           out_shardings=(state_sh, None),

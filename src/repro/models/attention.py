@@ -267,11 +267,12 @@ def _fused_decode_call(cfg: ArchConfig, flags, q, k, v, k_arena, v_arena,
         return fused_flash_decode(q_l, k_l, v_l, ka_l, va_l, tbl_l, pos_l,
                                   rope_theta=cfg.rope_theta, split_k=split_k)
 
-    return paging.shard_map_compat(
-        body, mesh,
+    # outputs are genuinely sharded, never replicated: no vma check
+    return jax.shard_map(
+        body, mesh=mesh,
         in_specs=(hspec, hspec, hspec, hspec, hspec,
                   P(None, None), P(None)),
-        out_specs=(hspec, hspec, hspec))(
+        out_specs=(hspec, hspec, hspec), check_vma=False)(
             q, k, v, k_arena, v_arena, tables, pos)
 
 

@@ -107,20 +107,6 @@ def use_fused_decode(cfg, flags) -> bool:
             and (shards == 1 or cfg.num_kv_heads % shards == 0))
 
 
-def shard_map_compat(f, mesh, *, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the public API (>= 0.6)
-    with the varying-manual-axes check disabled, else the 0.4.x
-    experimental entry point with ``check_rep`` disabled (the fused
-    decode outputs are genuinely sharded, never replicated)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def fused_page_size(max_len: int, preferred: int = 8) -> int:
     """Page granularity for viewing a contiguous slot row as an arena.
 

@@ -73,6 +73,7 @@ class LLMEngine:
             flags = dataclasses.replace(flags, decode_shards=self.tp,
                                         decode_mesh=mesh)
         self.flags = flags
+        self._check_fused_vmem()
         if params is None:
             params = self.model.init(jax.random.PRNGKey(seed))
         if mesh is not None:
@@ -208,6 +209,29 @@ class LLMEngine:
             self.params, {"tokens": jnp.asarray(tokens, jnp.int32)})
         return np.asarray(next_tok), cache
 
+    def _check_fused_vmem(self) -> None:
+        """On a TPU the gathered fused-decode variant stages a whole
+        ``max_len`` K/V row in VMEM: refuse here a row Mosaic would
+        refuse at compile time (the split-K variant has no such bound)."""
+        from ..kernels.flash_decode import (GATHER_VMEM_LIMIT,
+                                            gathered_vmem_bytes)
+        from ..models.paging import use_fused_decode
+        cfg, flags = self.cfg, self.flags
+        if (flags.fused_split_k or cfg.use_mla
+                or "attn" not in cfg.layer_kinds()
+                or not use_fused_decode(cfg, flags)
+                or jax.default_backend() != "tpu"):
+            return
+        need = gathered_vmem_bytes(
+            self.max_len, cfg.num_kv_heads // flags.decode_shards,
+            cfg.head_dim, jnp.dtype(cfg.dtype).itemsize)
+        if need > GATHER_VMEM_LIMIT:
+            raise ValueError(
+                f"use_fused_decode: a {self.max_len}-token row needs about "
+                f"{need >> 20} MiB of VMEM in the gathered variant, over "
+                f"its {GATHER_VMEM_LIMIT >> 20} MiB cap; set "
+                f"fused_split_k=True or lower max_len")
+
     def _check_paged(self, block_size: int) -> None:
         check_paged_support(self.cfg)
         if self.max_len % block_size != 0:
@@ -328,11 +352,13 @@ class LLMEngine:
         # first byte.  Jitted steps preserve these shardings (GSPMD
         # propagates them through the scatter/gather; the leak fixture
         # in tests/conftest.py asserts no silent replication drift).
+        # Zeros are made under jit with the output sharding, so each
+        # rank allocates only its shard: no whole arena on one device.
         from ..sharding.rules import cache_specs
         specs = cache_specs(abstract, self.mesh)
-        return jax.tree.map(
-            lambda s, sh: jax.device_put(jnp.zeros(s.shape, s.dtype), sh),
-            abstract, specs)
+        return jax.jit(lambda: jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype), abstract),
+            out_shardings=specs)()
 
     @property
     def mesh_desc(self) -> Dict[str, Any]:
