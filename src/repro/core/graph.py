@@ -181,6 +181,10 @@ class Graph:
             self.tracer: Tracer = NullTracer()
         else:
             self.tracer = Tracer(config.trace_buffer_size)
+        # a `graph.run {node}` span on the profiler's clock around every
+        # calculator run, gated as the ring is
+        self._span = trace_mod.span_factory(
+            not isinstance(self.tracer, NullTracer))
 
         # ---- build nodes ----------------------------------------------
         self.nodes: List[_NodeRuntime] = []
@@ -516,16 +520,17 @@ class Graph:
         err: Optional[BaseException] = None
         source_more = True
         try:
-            if action == "open":
-                node.calculator.open(node.ctx)
-            elif action == "process":
-                if input_set is not None:
-                    node.ctx.inputs = input_set
-                result = node.calculator.process(node.ctx)
-                if node.is_source:
-                    source_more = bool(result)
-            elif action == "close":
-                node.calculator.close(node.ctx)
+            with self._span("graph.run", node=node.name):
+                if action == "open":
+                    node.calculator.open(node.ctx)
+                elif action == "process":
+                    if input_set is not None:
+                        node.ctx.inputs = input_set
+                    result = node.calculator.process(node.ctx)
+                    if node.is_source:
+                        source_more = bool(result)
+                elif action == "close":
+                    node.calculator.close(node.ctx)
         except BaseException as e:  # noqa: BLE001 - error terminates run
             err = e
 

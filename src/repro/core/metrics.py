@@ -357,6 +357,16 @@ class MetricsRegistry:
     def histogram(self, name: str, help: str = "") -> Histogram:
         return self._get(Histogram, name, help)
 
+    def share(self, *metrics) -> None:
+        """Hold instruments owned elsewhere under their own names: one
+        process-wide instrument (the engine's compile counter) then
+        shows in every registry that shares it."""
+        with self._lock:
+            for m in metrics:
+                if self._metrics.setdefault(m.name, m) is not m:
+                    raise ValueError(f"metric {m.name!r} already "
+                                     f"registered")
+
     def names(self) -> List[str]:
         return sorted(self._metrics)
 

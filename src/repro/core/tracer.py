@@ -12,13 +12,22 @@ the lock-free ring-buffer approach the paper describes.  When tracing is
 disabled the graph holds a :class:`NullTracer` whose ``record`` is a no-op —
 and like the paper's compiler flag, ``repro.core.tracer.COMPILED_OUT = True``
 removes even that call overhead by swapping the graph's hooks out entirely.
+
+The ring runs on ``perf_counter``; spans that must line up with device
+work go to the JAX profiler instead (:func:`span_factory`), and each
+:class:`Tracer` records its start on the profiler's host clock too
+(``profiler_t0_ns``), so ring events convert onto a profiler trace's
+time base (:meth:`Tracer.profiler_ns`).
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
-from typing import Dict, List, NamedTuple, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 # Event types
 READY = "READY"
@@ -43,6 +52,29 @@ SPAN = "SPAN"
 # using a compiler flag".
 COMPILED_OUT = False
 
+#: the span handed out when spans are off: entering it does nothing
+NULL_SPAN = contextlib.nullcontext()
+
+
+def null_span(name: str = "", **args) -> contextlib.nullcontext:
+    return NULL_SPAN
+
+
+def span_factory(enabled: bool
+                 ) -> Callable[..., contextlib.AbstractContextManager]:
+    """``jax.profiler.TraceAnnotation`` when ``enabled``, else
+    :func:`null_span`.  A TraceAnnotation is a span on the profiler's
+    host clock, recorded only while a profiler trace is being taken
+    (``jax.profiler.start_trace``); otherwise entering it costs about a
+    microsecond and records nothing."""
+    return TraceAnnotation if enabled else null_span
+
+
+def profiling() -> bool:
+    """True while a profiler trace is being taken: the time to compute
+    span arguments that cost more than the span itself."""
+    return TraceAnnotation.is_enabled()
+
 
 class TraceEvent(NamedTuple):
     event_time: int          # perf_counter_ns
@@ -61,6 +93,9 @@ class Tracer:
         self._next = itertools.count()
         self._recorded = 0       # high-water mark, read by events()
         self._t0 = time.perf_counter_ns()
+        # the same instant on the profiler's host clock (wall-clock ns,
+        # the base of TraceAnnotation and device events in an xplane)
+        self.profiler_t0_ns = time.time_ns()
         # OS thread ident -> small dense id.  dict.setdefault is atomic in
         # CPython, so this stays lock-free; the id counter may skip values
         # when two threads race their first record, which is harmless.
@@ -81,6 +116,12 @@ class Tracer:
             stream_id, packet_timestamp, packet_data_id, tid)
         if i >= self._recorded:  # benign race: analysis-time snapshot only
             self._recorded = i + 1
+
+    def profiler_ns(self, event_time: int) -> int:
+        """An event's ``event_time`` on the profiler's host clock
+        (wall-clock ns).  A loaded profile's event times count from its
+        ``profile_start_time``: subtract that to land on them."""
+        return self.profiler_t0_ns + int(event_time)
 
     # -- analysis (cold path) ---------------------------------------------
     def events(self) -> List[TraceEvent]:
@@ -163,7 +204,9 @@ class Tracer:
         import json
         with open(path, "w") as f:
             f.write(json.dumps({"node_names": node_names or {},
-                                "capacity": self.capacity}) + "\n")
+                                "capacity": self.capacity,
+                                "profiler_t0_ns": self.profiler_t0_ns})
+                    + "\n")
             for e in self.events():
                 f.write(json.dumps(list(e)) + "\n")
 
@@ -174,6 +217,7 @@ class Tracer:
         with open(path) as f:
             header = json.loads(f.readline())
             t = Tracer(header.get("capacity", 65536))
+            t.profiler_t0_ns = int(header.get("profiler_t0_ns", 0))
             for line in f:
                 e = TraceEvent(*json.loads(line))
                 i = next(t._next)
@@ -192,7 +236,9 @@ class Tracer:
         packet events become instants ("i"), GAUGE samples become counter
         ("C") tracks — so KV-block-pool occupancy plots as a pressure
         curve over the decode timeline — and SPAN lifecycle markers
-        (serving/observe.py) become instants on their thread track."""
+        (serving/observe.py) become instants on their thread track.
+        ``otherData.profiler_offset_us`` added to an event's ``ts`` gives
+        its time on the profiler's host clock."""
         import json
         names = node_names or {}
         evs = self.events()
@@ -238,7 +284,9 @@ class Tracer:
                              "packet_timestamp": e.packet_timestamp,
                              "packet_data_id": e.packet_data_id}})
         with open(path, "w") as f:
-            json.dump({"traceEvents": out, "displayTimeUnit": "ms"}, f)
+            json.dump({"traceEvents": out, "displayTimeUnit": "ms",
+                       "otherData": {"profiler_offset_us":
+                                     self.profiler_t0_ns / 1e3}}, f)
 
 
 class NullTracer(Tracer):
@@ -247,6 +295,7 @@ class NullTracer(Tracer):
         self._buf = []
         self.capacity = 0
         self._t0 = 0
+        self.profiler_t0_ns = 0
 
     def record(self, *a, **k) -> None:  # pragma: no cover - trivial
         pass

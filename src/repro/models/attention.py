@@ -340,26 +340,32 @@ def _paged_decode(params, cfg: ArchConfig, q, k, v, cache, cache_pos,
         # trash block 0 — the write is harmless and the positions stay
         # masked (the scheduler backs every position it will keep).
         pos_s = pos[:, None] + jnp.arange(S_q)[None, :]         # [B,S']
-        blk, off = paging.tail_refs(block_tables, pos_s, bs)
-        k_new = paging.scatter_token(cache["k"], blk, off, k)
-        v_new = paging.scatter_token(cache["v"], blk, off, v)
-        k_seq = paging.gather_pages(k_new, block_tables)
-        v_seq = paging.gather_pages(v_new, block_tables)
+        with jax.named_scope("kv_write"):
+            blk, off = paging.tail_refs(block_tables, pos_s, bs)
+            k_new = paging.scatter_token(cache["k"], blk, off, k)
+            v_new = paging.scatter_token(cache["v"], blk, off, v)
+        with jax.named_scope("gather"):
+            k_seq = paging.gather_pages(k_new, block_tables)
+            v_seq = paging.gather_pages(v_new, block_tables)
         valid = jnp.arange(P * bs)[None, None, :] <= pos_s[:, :, None]
         mask = valid[:, None, None]                       # [B,1,1,S',T]
         out = _grouped_attention(q, k_seq, v_seq, mask)
         y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
         return y, {"k": k_new, "v": v_new}
-    blk, off = paging.tail_refs(block_tables, pos, bs)
-    k_new = paging.scatter_token(cache["k"], blk, off, k[:, 0])
-    v_new = paging.scatter_token(cache["v"], blk, off, v[:, 0])
+    # named scopes under the layer's `attn`: the page gather and the K/V
+    # write carry `attn/gather` and `attn/kv_write` in their op paths
+    with jax.named_scope("kv_write"):
+        blk, off = paging.tail_refs(block_tables, pos, bs)
+        k_new = paging.scatter_token(cache["k"], blk, off, k[:, 0])
+        v_new = paging.scatter_token(cache["v"], blk, off, v[:, 0])
     if flags is not None and getattr(flags, "use_paged_kernel", False):
         from ..kernels.ops import paged_attention
         out = paged_attention(q[:, 0], k_new, v_new, block_tables,
                               pos)[:, None]
     else:
-        k_seq = paging.gather_pages(k_new, block_tables)
-        v_seq = paging.gather_pages(v_new, block_tables)
+        with jax.named_scope("gather"):
+            k_seq = paging.gather_pages(k_new, block_tables)
+            v_seq = paging.gather_pages(v_new, block_tables)
         valid = paging.valid_mask(P * bs, pos)
         mask = valid[:, None, None, None, :]         # [B,1,1,1,T]
         out = _grouped_attention(q, k_seq, v_seq, mask)

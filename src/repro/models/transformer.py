@@ -179,7 +179,8 @@ def layer_apply(params, cfg: ArchConfig, kind: str, ffn_kind: str,
     layer cache (and leave the live state uncommitted) — the speculative
     verify/rewind machinery selects the accepted prefix's state out of it.
     """
-    h = rms_norm(params["norm1"], x, cfg.norm_eps, flags.fused_rmsnorm)
+    with jax.named_scope("norm"):
+        h = rms_norm(params["norm1"], x, cfg.norm_eps, flags.fused_rmsnorm)
     new_cache: Dict[str, Any] = {}
     decode = cache is not None
     extend = want_cache and prefix_kv is not None
@@ -196,91 +197,94 @@ def layer_apply(params, cfg: ArchConfig, kind: str, ffn_kind: str,
                     nw, od.astype(nw.dtype)),
                 c, cache["mixer"])
         return c
-    if kind == "attn":
-        if cfg.use_mla:
+    # one named scope per mixer kind, as layer_kinds() names them, kept
+    # in each op's metadata (docs/OBSERVABILITY.md, "Layer-kind scopes")
+    with jax.named_scope(kind):
+        if kind == "attn":
+            if cfg.use_mla:
+                if decode:
+                    y, c = mla_mod.mla_apply(
+                        params["mixer"], cfg, h, positions, cache["mixer"],
+                        cache_pos, block_tables=block_tables)
+                elif extend:
+                    y, c = mla_mod.mla_prefill_extend(
+                        params["mixer"], cfg, h, positions, prefix_kv["mixer"],
+                        prefix_len, max_cache_len, flags=flags)
+                elif want_cache:
+                    y, c = mla_mod.mla_prefill_into_cache(
+                        params["mixer"], cfg, h, positions, max_cache_len,
+                        flags=flags)
+                else:
+                    y, c = mla_mod.mla_apply(params["mixer"], cfg, h,
+                                             positions, flags=flags)
+            else:
+                impl = "flash" if flags.use_flash else flags.attn_impl
+                if decode:
+                    y, c = attn.attention_apply(params["mixer"], cfg, h,
+                                                positions, cache["mixer"],
+                                                cache_pos, impl, flags,
+                                                block_tables=block_tables)
+                elif extend:
+                    y, c = attn.prefill_extend_into_cache(
+                        params["mixer"], cfg, h, positions, prefix_kv["mixer"],
+                        prefix_len, max_cache_len, impl, flags)
+                elif want_cache:
+                    y, c = attn.prefill_into_cache(
+                        params["mixer"], cfg, h, positions, max_cache_len,
+                        impl, flags)
+                else:
+                    y, c = attn.attention_apply(params["mixer"], cfg, h,
+                                                positions, impl=impl,
+                                                flags=flags)
+        elif kind == "mamba":
             if decode:
-                y, c = mla_mod.mla_apply(params["mixer"], cfg, h, positions,
-                                         cache["mixer"], cache_pos,
-                                         block_tables=block_tables)
-            elif extend:
-                y, c = mla_mod.mla_prefill_extend(
-                    params["mixer"], cfg, h, positions, prefix_kv["mixer"],
-                    prefix_len, max_cache_len, flags=flags)
+                if h.shape[1] == 1 and not want_state_stack:
+                    y, c = mam.mamba_decode(params["mixer"], cfg, h,
+                                            cache["mixer"])
+                else:
+                    y, c, stk = mam.mamba_window(params["mixer"], cfg, h,
+                                                 cache["mixer"],
+                                                 want_stack=want_state_stack)
+                c = commit_state(c)
             elif want_cache:
-                y, c = mla_mod.mla_prefill_into_cache(
-                    params["mixer"], cfg, h, positions, max_cache_len,
-                    flags=flags)
+                y, c = mam.mamba_prefill_into_cache(params["mixer"], cfg, h)
             else:
-                y, c = mla_mod.mla_apply(params["mixer"], cfg, h, positions,
-                                         flags=flags)
-        else:
-            impl = "flash" if flags.use_flash else flags.attn_impl
+                y, c = mam.mamba_apply(params["mixer"], cfg, h)
+        elif kind == "mlstm":
+            # sequence-parallel scan pays off once S spans many model shards
+            use_sp = flags.model_size > 1 and x.shape[1] >= 8192
             if decode:
-                y, c = attn.attention_apply(params["mixer"], cfg, h,
-                                            positions, cache["mixer"],
-                                            cache_pos, impl, flags,
-                                            block_tables=block_tables)
-            elif extend:
-                y, c = attn.prefill_extend_into_cache(
-                    params["mixer"], cfg, h, positions, prefix_kv["mixer"],
-                    prefix_len, max_cache_len, impl, flags)
+                if h.shape[1] == 1 and not want_state_stack:
+                    y, c = xl.mlstm_decode(params["mixer"], cfg, h,
+                                           cache["mixer"])
+                else:
+                    y, c, stk = xl.mlstm_window(params["mixer"], cfg, h,
+                                                cache["mixer"],
+                                                want_stack=want_state_stack)
+                c = commit_state(c)
+            elif use_sp:
+                y, c = xl.mlstm_apply_sp(params["mixer"], cfg, h, flags,
+                                         want_cache=want_cache)
             elif want_cache:
-                y, c = attn.prefill_into_cache(
-                    params["mixer"], cfg, h, positions, max_cache_len,
-                    impl, flags)
+                y, c = xl.mlstm_prefill_into_cache(params["mixer"], cfg, h)
             else:
-                y, c = attn.attention_apply(params["mixer"], cfg, h,
-                                            positions, impl=impl,
-                                            flags=flags)
-    elif kind == "mamba":
-        if decode:
-            if h.shape[1] == 1 and not want_state_stack:
-                y, c = mam.mamba_decode(params["mixer"], cfg, h,
-                                        cache["mixer"])
+                y, c = xl.mlstm_apply(params["mixer"], cfg, h)
+        elif kind == "slstm":
+            if decode:
+                if h.shape[1] == 1 and not want_state_stack:
+                    y, c = xl.slstm_decode(params["mixer"], cfg, h,
+                                           cache["mixer"])
+                else:
+                    y, c, stk = xl.slstm_window(params["mixer"], cfg, h,
+                                                cache["mixer"],
+                                                want_stack=want_state_stack)
+                c = commit_state(c)
+            elif want_cache:
+                y, c = xl.slstm_prefill_into_cache(params["mixer"], cfg, h)
             else:
-                y, c, stk = mam.mamba_window(params["mixer"], cfg, h,
-                                             cache["mixer"],
-                                             want_stack=want_state_stack)
-            c = commit_state(c)
-        elif want_cache:
-            y, c = mam.mamba_prefill_into_cache(params["mixer"], cfg, h)
-        else:
-            y, c = mam.mamba_apply(params["mixer"], cfg, h)
-    elif kind == "mlstm":
-        # sequence-parallel scan pays off once S spans many model shards
-        use_sp = flags.model_size > 1 and x.shape[1] >= 8192
-        if decode:
-            if h.shape[1] == 1 and not want_state_stack:
-                y, c = xl.mlstm_decode(params["mixer"], cfg, h,
-                                       cache["mixer"])
-            else:
-                y, c, stk = xl.mlstm_window(params["mixer"], cfg, h,
-                                            cache["mixer"],
-                                            want_stack=want_state_stack)
-            c = commit_state(c)
-        elif use_sp:
-            y, c = xl.mlstm_apply_sp(params["mixer"], cfg, h, flags,
-                                     want_cache=want_cache)
-        elif want_cache:
-            y, c = xl.mlstm_prefill_into_cache(params["mixer"], cfg, h)
-        else:
-            y, c = xl.mlstm_apply(params["mixer"], cfg, h)
-    elif kind == "slstm":
-        if decode:
-            if h.shape[1] == 1 and not want_state_stack:
-                y, c = xl.slstm_decode(params["mixer"], cfg, h,
-                                       cache["mixer"])
-            else:
-                y, c, stk = xl.slstm_window(params["mixer"], cfg, h,
-                                            cache["mixer"],
-                                            want_stack=want_state_stack)
-            c = commit_state(c)
-        elif want_cache:
-            y, c = xl.slstm_prefill_into_cache(params["mixer"], cfg, h)
-        else:
-            y, c = xl.slstm_apply(params["mixer"], cfg, h)
-    else:  # pragma: no cover
-        raise ValueError(kind)
+                y, c = xl.slstm_apply(params["mixer"], cfg, h)
+        else:  # pragma: no cover
+            raise ValueError(kind)
     new_cache["mixer"] = c
     if want_state_stack and decode:
         # Mirror the layer-cache structure so rewind can tree_map the
@@ -294,17 +298,24 @@ def layer_apply(params, cfg: ArchConfig, kind: str, ffn_kind: str,
     x = x + y
 
     if "cross" in params and memory_kv is not None:
-        hc = rms_norm(params["cross_norm"], x, cfg.norm_eps,
-                      flags.fused_rmsnorm)
-        x = x + _cross_attention(params["cross"], cfg, hc, memory_kv, flags)
+        with jax.named_scope("norm"):
+            hc = rms_norm(params["cross_norm"], x, cfg.norm_eps,
+                          flags.fused_rmsnorm)
+        with jax.named_scope("attn"):
+            x = x + _cross_attention(params["cross"], cfg, hc, memory_kv,
+                                     flags)
 
     aux = jnp.zeros((), jnp.float32)
     if "ffn" in params:
-        h2 = rms_norm(params["norm2"], x, cfg.norm_eps, flags.fused_rmsnorm)
+        with jax.named_scope("norm"):
+            h2 = rms_norm(params["norm2"], x, cfg.norm_eps,
+                          flags.fused_rmsnorm)
         if ffn_kind == "moe":
-            y2, aux = moe_mod.moe_apply(params["ffn"], cfg, h2, flags)
+            with jax.named_scope("ffn.moe"):
+                y2, aux = moe_mod.moe_apply(params["ffn"], cfg, h2, flags)
         else:
-            y2 = mlp_apply(params["ffn"], h2)
+            with jax.named_scope("ffn"):
+                y2 = mlp_apply(params["ffn"], h2)
         x = x + y2
     x = constrain_batch(x, flags)
     return x, aux, (new_cache if (decode or want_cache) else None)
@@ -628,7 +639,8 @@ def prefill(params, cfg: ArchConfig, tokens: jax.Array, max_cache_len: int,
             flags: RuntimeFlags = DEFAULT_FLAGS):
     """Run the prompt, return (last-token logits [B,V], cache)."""
     dt = jnp.dtype(cfg.dtype)
-    x = embed_apply(params["embed"], tokens, dt)
+    with jax.named_scope("embed"):
+        x = embed_apply(params["embed"], tokens, dt)
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(dt), x], axis=1)
     x = constrain_batch(x, flags)
@@ -666,8 +678,10 @@ def prefill(params, cfg: ArchConfig, tokens: jax.Array, max_cache_len: int,
         x, group_caches = jax.lax.scan(group_step, x, params["blocks"])
         cache["blocks"] = group_caches
 
-    x = rms_norm(params["final_norm"], x, cfg.norm_eps, flags.fused_rmsnorm)
-    logits = _logits(params, cfg, x[:, -1:, :])[:, 0]
+    with jax.named_scope("head"):
+        x = rms_norm(params["final_norm"], x, cfg.norm_eps,
+                     flags.fused_rmsnorm)
+        logits = _logits(params, cfg, x[:, -1:, :])[:, 0]
     return logits, cache
 
 
@@ -787,7 +801,8 @@ def decode_step(params, cfg: ArchConfig, tokens: jax.Array,
     (state after every window position) and every other leaf a
     zero-size placeholder."""
     dt = jnp.dtype(cfg.dtype)
-    x = embed_apply(params["embed"], tokens, dt)
+    with jax.named_scope("embed"):
+        x = embed_apply(params["embed"], tokens, dt)
     x = constrain_batch(x, flags)
     B, S_q = x.shape[0], x.shape[1]
     cache_pos = jnp.asarray(cache_pos, jnp.int32)
@@ -822,10 +837,11 @@ def decode_step(params, cfg: ArchConfig, tokens: jax.Array,
         def group_step(carry, scanned):
             x, blocks_cache = carry
             group_params, idx = scanned
-            group_cache = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, idx, 0,
-                                                       keepdims=False),
-                blocks_cache)
+            with jax.named_scope("cache"):
+                group_cache = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, idx, 0, keepdims=False),
+                    blocks_cache)
             new_group = {}
             group_stacks = {}
             for j, (k, f) in enumerate(pattern):
@@ -843,10 +859,11 @@ def decode_step(params, cfg: ArchConfig, tokens: jax.Array,
                 if mkv is not None:
                     c["cross"] = mkv
                 new_group[f"l{j}"] = c
-            blocks_cache = jax.tree.map(
-                lambda full, new: jax.lax.dynamic_update_index_in_dim(
-                    full, new.astype(full.dtype), idx, 0),
-                blocks_cache, new_group)
+            with jax.named_scope("cache"):
+                blocks_cache = jax.tree.map(
+                    lambda full, new: jax.lax.dynamic_update_index_in_dim(
+                        full, new.astype(full.dtype), idx, 0),
+                    blocks_cache, new_group)
             return (x, blocks_cache), group_stacks
 
         (x, blocks_cache), block_stacks = jax.lax.scan(
@@ -856,8 +873,10 @@ def decode_step(params, cfg: ArchConfig, tokens: jax.Array,
         if want_state_stacks:
             stacks["blocks"] = block_stacks
 
-    x = rms_norm(params["final_norm"], x, cfg.norm_eps, flags.fused_rmsnorm)
-    logits = _logits(params, cfg, x)
+    with jax.named_scope("head"):
+        x = rms_norm(params["final_norm"], x, cfg.norm_eps,
+                     flags.fused_rmsnorm)
+        logits = _logits(params, cfg, x)
     logits = logits if all_logits else logits[:, 0]
     if want_state_stacks:
         return logits, new_cache, stacks

@@ -43,7 +43,9 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from ..core import tracer as trace_mod
 from .kvcache.backend import CacheBackend, CachePressure
+from .observe import decode_span_args
 from .speculative import lookup_draft
 
 _EMPTY_DRAFT = np.zeros(0, np.int32)
@@ -462,7 +464,11 @@ class Scheduler:
         request with a TTFT target may preempt a strictly-lower-priority
         active request when no slot is free (SLO-aware admission — the
         deadline feeds the same priority+preemption machinery pressure
-        uses)."""
+        uses).  The whole tick phase is the ``serve.admit`` span."""
+        with self.obs.phase("serve.admit"):
+            return self._admit()
+
+    def _admit(self) -> List[TokenEvent]:
         events: List[TokenEvent] = self._lifecycle_sweep()
         # continue in-flight chunked ingests first (FIFO fairness)
         for req in list(self.ingesting):
@@ -509,11 +515,14 @@ class Scheduler:
         for r in reqs:
             by_len.setdefault(int(r.prompt.size), []).append(r)
         for grp in sorted(by_len.values(), key=lambda g: g[0].arrival):
-            t0 = self.obs.now() if self._observe else 0.0
-            first = self.backend.prefill_group(grp)
-            if self._observe:
-                self.obs.prefill((self.obs.now() - t0) * 1e3,
-                                 sum(int(r.prompt.size) for r in grp))
+            with self.obs.phase("serve.prefill",
+                                tokens=int(grp[0].prompt.size),
+                                rows=len(grp)):
+                t0 = self.obs.now() if self._observe else 0.0
+                first = self.backend.prefill_group(grp)
+                if self._observe:
+                    self.obs.prefill((self.obs.now() - t0) * 1e3,
+                                     sum(int(r.prompt.size) for r in grp))
             for i, req in enumerate(grp):
                 self.ingesting.remove(req)
                 req.ingested = req.prompt.size
@@ -535,11 +544,13 @@ class Scheduler:
             else min(len(seq), start + self.chunk)
         while True:
             try:
-                t0 = self.obs.now() if self._observe else 0.0
-                tok = self.backend.ingest(req, seq, start, end)
-                if self._observe:
-                    self.obs.chunk(req, start, end,
-                                   (self.obs.now() - t0) * 1e3)
+                with self.obs.phase("serve.prefill", tokens=end - start,
+                                    rows=1):
+                    t0 = self.obs.now() if self._observe else 0.0
+                    tok = self.backend.ingest(req, seq, start, end)
+                    if self._observe:
+                        self.obs.chunk(req, start, end,
+                                       (self.obs.now() - t0) * 1e3)
                 break
             except CachePressure:
                 if self._observe:
@@ -598,16 +609,18 @@ class Scheduler:
         # back every write position with memory, preempting if needed;
         # a speculating row backs its whole kept window [pos, pos+|draft|]
         # (the +1 bonus token is emitted but not written this tick)
-        for req in list(self._decoding()):
-            if req.slot < 0 or self.slots[req.slot] is not req:
-                continue                    # preempted by an earlier grow
-            lo = int(self.positions[req.slot])
-            for p in range(lo, lo + drafts.get(req, _EMPTY_DRAFT).size + 1):
-                while (req.slot >= 0 and self.slots[req.slot] is req
-                       and not self.backend.grow(req, p)):
-                    self._preempt(self._pick_victim())
+        with self.obs.phase("serve.grow"):
+            for req in list(self._decoding()):
                 if req.slot < 0 or self.slots[req.slot] is not req:
-                    break
+                    continue                # preempted by an earlier grow
+                lo = int(self.positions[req.slot])
+                for p in range(lo,
+                               lo + drafts.get(req, _EMPTY_DRAFT).size + 1):
+                    while (req.slot >= 0 and self.slots[req.slot] is req
+                           and not self.backend.grow(req, p)):
+                        self._preempt(self._pick_victim())
+                    if req.slot < 0 or self.slots[req.slot] is not req:
+                        break
         active = np.zeros(self.num_slots, bool)
         for req in self._decoding():
             active[req.slot] = True
@@ -617,18 +630,22 @@ class Scheduler:
                   if r.slot >= 0 and self.slots[r.slot] is r}
         if drafts:
             return self._verify_tick(drafts, active)
-        t0 = self.obs.now() if self._observe else 0.0
-        next_tok = self.backend.decode(self.last_tokens, self.positions,
-                                       active)
-        if self._observe:
-            self.obs.decode_tick((self.obs.now() - t0) * 1e3,
-                                 int(active.sum()))
+        span_args = decode_span_args(self.positions, active) \
+            if self._observe and trace_mod.profiling() else {}
+        with self.obs.phase("serve.decode", **span_args):
+            t0 = self.obs.now() if self._observe else 0.0
+            next_tok = self.backend.decode(self.last_tokens,
+                                           self.positions, active)
+            if self._observe:
+                self.obs.decode_tick((self.obs.now() - t0) * 1e3,
+                                     int(active.sum()))
         self.stats["decode_steps"] += 1
         events = []
-        for slot in np.nonzero(active)[0]:
-            req = self.slots[slot]
-            self.positions[slot] += 1
-            events.append(self._record(req, int(next_tok[slot])))
+        with self.obs.phase("serve.emit"):
+            for slot in np.nonzero(active)[0]:
+                req = self.slots[slot]
+                self.positions[slot] += 1
+                events.append(self._record(req, int(next_tok[slot])))
         return events
 
     # -- speculative decoding ---------------------------------------------
@@ -677,11 +694,12 @@ class Scheduler:
         window[:, 0] = self.last_tokens
         for r, d in drafts.items():
             window[r.slot, 1:1 + d.size] = d
-        t0 = self.obs.now() if self._observe else 0.0
-        guess = self.backend.verify(window, self.positions, active)
-        if self._observe:
-            self.obs.verify_tick((self.obs.now() - t0) * 1e3,
-                                 int(active.sum()))
+        with self.obs.phase("serve.verify", width=K + 1):
+            t0 = self.obs.now() if self._observe else 0.0
+            guess = self.backend.verify(window, self.positions, active)
+            if self._observe:
+                self.obs.verify_tick((self.obs.now() - t0) * 1e3,
+                                     int(active.sum()))
         self.stats["decode_steps"] += 1
         self.stats["spec_steps"] += 1
         events: List[TokenEvent] = []

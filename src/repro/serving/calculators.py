@@ -282,7 +282,12 @@ class ContinuousBatchCalculator(Calculator):
         tick = ctx.inputs["TICK"]
         if not tick.is_empty():
             self._tick_pending = False
-            self._emit_events(ctx, self.sched.admit() + self.sched.step())
+            # one tick = admission + one decode step + emission; its
+            # phases nest under this span (docs/OBSERVABILITY.md)
+            with self.observer.phase("serve.tick", step=tick.payload):
+                events = self.sched.admit() + self.sched.step()
+                with self.observer.phase("serve.emit"):
+                    self._emit_events(ctx, events)
         if self.sched.has_work() and not self._tick_pending:
             # one tick in flight at a time: request bursts queue behind it
             # and are admitted together at the next round.  (Payload must

@@ -21,7 +21,7 @@ Two decode modes:
 """
 from __future__ import annotations
 
-import time
+import threading
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -29,7 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import tracer as trace_mod
-from ..core.metrics import MetricsRegistry, NullRegistry
+from ..core.metrics import (Counter, Histogram, MetricsRegistry,
+                            NullRegistry)
 from ..models.config import ArchConfig
 from ..models.model import Model
 from ..models.transformer import (DEFAULT_FLAGS, RuntimeFlags,
@@ -42,11 +43,46 @@ from ..runtime.steps import (kernel_path, make_decode_step, make_extend_step,
                              make_slot_insert, make_state_extend_step,
                              make_state_rewind, make_state_verify_step,
                              make_verify_step)
+from .observe import decode_span_args
 
 #: cache layouts whose recurrent layers live in O(1) state slabs — decode
 #: masks state commits per row, and verify returns per-position state
 #: stacks for rewind (docs/STATE_CACHE.md)
 STATE_KINDS = ("state", "hybrid")
+
+#: JAX's monitoring event for one XLA backend compile (or load from the
+#: persistent cache), with the jitted function's name as ``fun_name``
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_compile_lock = threading.Lock()
+_compile_metrics: Optional[Tuple[Any, Any]] = None
+
+
+def compile_metrics():
+    """The process-wide ``(engine.compiles, engine.compile_ms)`` pair,
+    fed by one listener on :data:`COMPILE_EVENT`, registered on first
+    use.  A retrace compiles again and counts again.  JAX's event does
+    not say which engine compiled, so every engine's registry shares
+    these two instruments and counts the whole process's compiles."""
+    global _compile_metrics
+    with _compile_lock:
+        if _compile_metrics is None:
+            compiles = Counter(
+                "engine.compiles", "XLA backend compiles in this "
+                "process, retraces included, by jitted function")
+            compile_ms = Histogram(
+                "engine.compile_ms", "wall time of one XLA backend "
+                "compile in this process, by jitted function (ms)")
+
+            def on_duration(event, secs, fun_name="?", **_):
+                if event == COMPILE_EVENT:
+                    compiles.inc(fun=fun_name)
+                    compile_ms.observe(secs * 1e3, fun=fun_name)
+
+            jax.monitoring.register_event_duration_secs_listener(
+                on_duration)
+            _compile_metrics = (compiles, compile_ms)
+        return _compile_metrics
 
 
 class LLMEngine:
@@ -81,19 +117,19 @@ class LLMEngine:
             params = jax.device_put(
                 params, param_specs(self.model.template, mesh))
         self.params = params
-        # Engine-side profiling registry (docs/OBSERVABILITY.md): jit
-        # compile counts + compile wall time per (step, layout, width)
-        # cache entry.  GraphServer.metrics() merges it with the
-        # scheduler's registry.  Under tracer.COMPILED_OUT the registry
-        # is a no-op sink.
-        self.metrics: MetricsRegistry = \
-            NullRegistry() if trace_mod.COMPILED_OUT else MetricsRegistry()
-        self._prefill = self._timed(
-            jax.jit(make_prefill_step(self.model, max_len, flags)),
-            "prefill", "batch")
-        self._decode = self._timed(
-            jax.jit(make_decode_step(self.model, flags)),
-            "decode", "batch")
+        # Engine-side profiling registry (docs/OBSERVABILITY.md): the
+        # process-wide compile counter and the kernel-path counter.
+        # GraphServer.metrics() merges it with the scheduler's registry.
+        # Under tracer.COMPILED_OUT the registry is a no-op sink and the
+        # engine opens no profiler spans.
+        if trace_mod.COMPILED_OUT:
+            self.metrics: MetricsRegistry = NullRegistry()
+        else:
+            self.metrics = MetricsRegistry()
+            self.metrics.share(*compile_metrics())
+        self._span = trace_mod.span_factory(not trace_mod.COMPILED_OUT)
+        self._prefill = jax.jit(make_prefill_step(self.model, max_len, flags))
+        self._decode = jax.jit(make_decode_step(self.model, flags))
         # serving jits, built lazily per cache layout: key is
         # (backend.kind, block_size); extend steps add prefix_len,
         # verify steps add the window width 1+k
@@ -101,74 +137,34 @@ class LLMEngine:
         self._extend_steps: Dict[Tuple, Any] = {}
         self._verify_steps: Dict[Tuple, Any] = {}
         self._state_rewind = None       # built on first verify/truncate
-        # per-(step, layout) cache of kernel-path metric handles +
-        # resolved label sets (_observe_kernel runs on every decode
-        # tick; keep it off the registry lookup path)
-        self._kernel_obs: Dict[Tuple, Tuple] = {}
-
-    def _timed(self, fn, step: str, layout: str, width: str = ""):
-        """Wrap a jitted step: the first call (= trace + compile + run)
-        is timed to a ``jax.block_until_ready`` barrier and recorded as
-        one jit-cache compile; later calls pay one Python-level
-        indirection and nothing else."""
-        state = {"first": True}
-
-        def wrapped(*args, **kw):
-            if state["first"]:
-                state["first"] = False
-                t0 = time.perf_counter()
-                out = fn(*args, **kw)
-                jax.block_until_ready(out)
-                dt_ms = (time.perf_counter() - t0) * 1e3
-                self.metrics.counter(
-                    "engine.jit_compiles",
-                    "jitted serving steps compiled, by cache key").inc(
-                        step=step, layout=layout, width=width)
-                self.metrics.histogram(
-                    "engine.jit_compile_ms",
-                    "first-call wall time per jit cache entry "
-                    "(trace + compile + run)").observe(
-                        dt_ms, step=step, layout=layout, width=width)
-                return out
-            return fn(*args, **kw)
-
-        return wrapped
+        # per-(step, layout) bound kernel-path counter (_observe_kernel
+        # runs on every decode tick; keep it off the registry lookup path)
+        self._kernel_obs: Dict[Tuple, Any] = {}
 
     @staticmethod
     def _layout(backend) -> str:
         return f"{backend.kind}/{getattr(backend, 'block_size', 0)}"
 
-    def _observe_kernel(self, step: str, backend, t0: float) -> None:
-        """Record which attention implementation served a decode/verify
-        step (``fused`` Pallas flash-decode vs the gather ``fallback``)
-        and its wall time — so a silent fall-off the fast path shows up
-        in ``metrics_text()``, not just as degraded throughput.  The
-        timer spans the host-side token conversion, i.e. includes the
-        device sync.  Runs on every decode tick: the dispatch decision,
-        label set, and metric handles are resolved once per
-        (step, layout) and cached."""
+    def _observe_kernel(self, step: str, backend) -> None:
+        """Count which attention implementation served a decode/verify
+        step (``fused`` Pallas flash-decode vs the gather ``fallback``),
+        so a silent fall-off the fast path shows up in
+        ``metrics_text()``, not just as degraded throughput.  Runs on
+        every decode tick: the dispatch decision, label set and counter
+        handle are resolved once per (step, layout) and cached."""
         if not self.metrics.enabled:
             return
-        dt_ms = (time.perf_counter() - t0) * 1e3
         key = (step, backend.kind, getattr(backend, "block_size", 0))
-        ent = self._kernel_obs.get(key)
-        if ent is None:
-            labels = {"path": kernel_path(self.cfg, self.flags,
-                                          backend.kind),
-                      "step": step, "layout": self._layout(backend)}
-            ent = (self.metrics.counter(
-                       "engine.kernel_path",
-                       "decode/verify steps by attention implementation "
-                       "(fused flash-decode kernel vs gather fallback)"
-                   ).bind(**labels),
-                   self.metrics.histogram(
-                       "engine.kernel_ms",
-                       "wall time per decode/verify step, by kernel "
-                       "path").bind(**labels))
-            self._kernel_obs[key] = ent
-        ctr, hist = ent
+        ctr = self._kernel_obs.get(key)
+        if ctr is None:
+            ctr = self.metrics.counter(
+                "engine.kernel_path",
+                "decode/verify steps by attention implementation "
+                "(fused flash-decode kernel vs gather fallback)"
+            ).bind(path=kernel_path(self.cfg, self.flags, backend.kind),
+                   step=step, layout=self._layout(backend))
+            self._kernel_obs[key] = ctr
         ctr.inc()
-        hist.observe(dt_ms)
 
     # ------------------------------------------------------------------
     # static-batch generation
@@ -204,10 +200,15 @@ class LLMEngine:
     def prefill(self, tokens: np.ndarray) -> Tuple[np.ndarray, Dict]:
         """Prefill [B, S] prompts; returns (first tokens [B], cache rows).
         All rows must share one length — the scheduler groups by length
-        so padding never perturbs positions (exactness over utilisation)."""
-        next_tok, cache = self._prefill(
-            self.params, {"tokens": jnp.asarray(tokens, jnp.int32)})
-        return np.asarray(next_tok), cache
+        so padding never perturbs positions (exactness over utilisation).
+        The ``engine.prefill`` span ends at the host sync on the first
+        tokens."""
+        rows, length = np.shape(tokens)
+        with self._span("engine.prefill", tokens=int(length),
+                        rows=int(rows)):
+            next_tok, cache = self._prefill(
+                self.params, {"tokens": jnp.asarray(tokens, jnp.int32)})
+            return np.asarray(next_tok), cache
 
     def _check_fused_vmem(self) -> None:
         """On a TPU the gathered fused-decode variant stages a whole
@@ -315,12 +316,11 @@ class LLMEngine:
                 insert = make_paged_insert(backend.block_size)
             else:
                 insert = make_slot_insert()
-            layout = f"{backend.kind}/{getattr(backend, 'block_size', 0)}"
             steps = {
-                "decode": self._timed(jax.jit(make_serve_decode_step(
+                "decode": jax.jit(make_serve_decode_step(
                     self.model, self.flags, paged=paged,
-                    masked_state=masked)), "serve_decode", layout),
-                "insert": self._timed(jax.jit(insert), "insert", layout),
+                    masked_state=masked)),
+                "insert": jax.jit(insert),
             }
             self._serve[key] = steps
         return steps
@@ -397,13 +397,14 @@ class LLMEngine:
         layout, 0 = skip page), or a ``(page_ids, slot)`` pair
         (hybrid)."""
         step = self._serve_steps(backend)["insert"]
-        if backend.kind == "hybrid":
-            page_ids, slot = dst
+        with self._span("engine.insert"):
+            if backend.kind == "hybrid":
+                page_ids, slot = dst
+                return step(cache, rows, jnp.asarray(row, jnp.int32),
+                            jnp.asarray(page_ids, jnp.int32),
+                            jnp.asarray(slot, jnp.int32))
             return step(cache, rows, jnp.asarray(row, jnp.int32),
-                        jnp.asarray(page_ids, jnp.int32),
-                        jnp.asarray(slot, jnp.int32))
-        return step(cache, rows, jnp.asarray(row, jnp.int32),
-                    jnp.asarray(dst, jnp.int32))
+                        jnp.asarray(dst, jnp.int32))
 
     def decode(self, backend, cache, last_tokens: np.ndarray,
                positions: np.ndarray, active: np.ndarray,
@@ -416,19 +417,21 @@ class LLMEngine:
         ``block_tables`` ([N, P] int32; inactive rows all-zero).  Returns
         ([N] next tokens, cache); inactive slots yield the pad token."""
         step = self._serve_steps(backend)["decode"]
-        t0 = time.perf_counter()
-        args = (self.params,
-                jnp.asarray(last_tokens, jnp.int32)[:, None],
-                cache,
-                jnp.asarray(positions, jnp.int32),
-                jnp.asarray(active, bool))
-        if backend.kind in ("paged", "hybrid"):
-            next_tok, cache = step(*args,
-                                   jnp.asarray(block_tables, jnp.int32))
-        else:
+        span = self._span
+        with span("engine.decode", **(decode_span_args(positions, active)
+                                      if trace_mod.profiling() else {})):
+            with span("engine.decode.inputs"):
+                args = (self.params,
+                        jnp.asarray(last_tokens, jnp.int32)[:, None],
+                        cache,
+                        jnp.asarray(positions, jnp.int32),
+                        jnp.asarray(active, bool))
+                if backend.kind in ("paged", "hybrid"):
+                    args += (jnp.asarray(block_tables, jnp.int32),)
             next_tok, cache = step(*args)
-        out = np.asarray(next_tok[:, 0])
-        self._observe_kernel("decode", backend, t0)
+            with span("engine.decode.sync"):
+                out = np.asarray(next_tok[:, 0])
+        self._observe_kernel("decode", backend)
         return out, cache
 
     def verify(self, backend, cache, tokens: np.ndarray,
@@ -451,11 +454,9 @@ class LLMEngine:
         key = (backend.kind, getattr(backend, "block_size", 0), width)
         step = self._verify_steps.get(key)
         if step is None:
-            step = self._timed(jax.jit(make_verify_step(
-                self.model, self.flags, paged=backend.kind == "paged")),
-                "verify", self._layout(backend), str(width))
+            step = jax.jit(make_verify_step(
+                self.model, self.flags, paged=backend.kind == "paged"))
             self._verify_steps[key] = step
-        t0 = time.perf_counter()
         args = (self.params, jnp.asarray(tokens, jnp.int32), cache,
                 jnp.asarray(positions, jnp.int32),
                 jnp.asarray(active, bool))
@@ -464,7 +465,7 @@ class LLMEngine:
         else:
             guess, cache = step(*args)
         out = np.asarray(guess)
-        self._observe_kernel("verify", backend, t0)
+        self._observe_kernel("verify", backend)
         return out, cache
 
     def verify_window(self, backend, cache, tokens: np.ndarray,
@@ -481,11 +482,9 @@ class LLMEngine:
                "stacks")
         step = self._verify_steps.get(key)
         if step is None:
-            step = self._timed(jax.jit(make_state_verify_step(
-                self.model, self.flags, paged=backend.kind == "hybrid")),
-                "verify_stacks", self._layout(backend), str(width))
+            step = jax.jit(make_state_verify_step(
+                self.model, self.flags, paged=backend.kind == "hybrid"))
             self._verify_steps[key] = step
-        t0 = time.perf_counter()
         args = (self.params, jnp.asarray(tokens, jnp.int32), cache,
                 jnp.asarray(positions, jnp.int32),
                 jnp.asarray(active, bool))
@@ -495,7 +494,7 @@ class LLMEngine:
         else:
             guess, cache, stacks = step(*args)
         out = np.asarray(guess)
-        self._observe_kernel("verify", backend, t0)
+        self._observe_kernel("verify", backend)
         return out, cache, stacks
 
     def state_rewind(self, cache, stacks, slot: int, idx: int):
@@ -504,9 +503,7 @@ class LLMEngine:
         into the live state slabs; attention leaves pass through.  One
         jitted function retraces per (layout, window width)."""
         if self._state_rewind is None:
-            self._state_rewind = self._timed(
-                jax.jit(make_state_rewind(self.model)),
-                "state_rewind", "state")
+            self._state_rewind = jax.jit(make_state_rewind(self.model))
         return self._state_rewind(cache, stacks,
                                   jnp.asarray(slot, jnp.int32),
                                   jnp.asarray(idx, jnp.int32))
@@ -536,8 +533,6 @@ class LLMEngine:
                     block_size=backend.block_size if kind == "paged"
                     else 0,
                     max_cache_len=self.max_len))
-            step = self._timed(step, "extend", self._layout(backend),
-                               str(int(prefix_len)))
             self._extend_steps[key] = step
         suffix = jnp.asarray(suffix_tokens, jnp.int32)[None]
         if kind == "paged":

@@ -12,6 +12,9 @@ Three pieces (docs/OBSERVABILITY.md):
   :data:`NULL_OBSERVER` instead (``enabled`` False), so the hot path
   carries no clock reads at all — the cost is measured, not assumed, by
   the ``observability`` section of ``benchmarks/serve_bench.py``.
+  :meth:`Observer.phase` is its span primitive on the profiler's clock:
+  the scheduler's timed sections open one, so each tick phase also
+  lands in a ``jax.profiler`` trace beside the device ops.
 
 * :class:`RequestTimeline` — reconstructs per-request lifecycles from
   the SPAN events: one Perfetto track per request
@@ -39,6 +42,8 @@ import sys
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+import numpy as np
+
 from ..core import tracer as trace_mod
 from ..core.metrics import MetricsRegistry, NullRegistry
 
@@ -46,6 +51,16 @@ from ..core.metrics import MetricsRegistry, NullRegistry
 # "finished:<reason>" (eos | length | cancelled | deadline).
 PHASES = ("submitted", "admitted", "chunk", "first_token", "token",
           "verify", "preempted", "replayed", "finished")
+
+
+def decode_span_args(positions, active) -> Dict[str, int]:
+    """Arguments of a decode span: the sum of the live rows' contexts
+    (cache position + 1) and the number of live rows.  About 8 us of
+    numpy: compute them only while a profiler trace is taken
+    (:func:`repro.core.tracer.profiling`)."""
+    act = np.asarray(active, bool)
+    return {"ctx": int((np.asarray(positions)[act] + 1).sum()),
+            "rows": int(act.sum())}
 
 
 def span_id(phase: str, rid: Any) -> str:
@@ -126,7 +141,13 @@ class Observer:
         self._g_mesh_devices.set(int(desc.get("devices", 1)))
         self._g_mesh_model.set(int(desc.get("axes", {}).get("model", 1)))
 
-    # -- span primitive ---------------------------------------------------
+    # -- span primitives --------------------------------------------------
+    def phase(self, name: str, **args):
+        """A span around one phase of a scheduler tick, on the profiler's
+        host clock (``jax.profiler.TraceAnnotation``): recorded while a
+        profiler trace is taken, about a microsecond otherwise."""
+        return trace_mod.TraceAnnotation(name, **args)
+
     def span(self, phase: str, rid: Any, seq: int = 0, value: int = 0) -> None:
         self.tracer.record(trace_mod.SPAN, self.node_id,
                            span_id(phase, rid), int(seq), int(value))
@@ -219,6 +240,9 @@ class _NullObserver(Observer):
     def set_mesh(self, *a, **k):
         pass
 
+    def phase(self, name, **args):
+        return trace_mod.NULL_SPAN
+
     def span(self, *a, **k):
         pass
 
@@ -282,9 +306,13 @@ class RequestTimeline:
     Build with :meth:`from_tracer` (or from a loaded trace file's
     events); render with :meth:`records` (JSON lifecycle dicts) or
     :meth:`export_perfetto` (one named track per request).
+    ``profiler_t0_ns`` is the ring's start on the profiler's host clock
+    (:attr:`Tracer.profiler_t0_ns`), carried into the Perfetto export.
     """
 
-    def __init__(self, events: List[trace_mod.TraceEvent]):
+    def __init__(self, events: List[trace_mod.TraceEvent],
+                 profiler_t0_ns: int = 0):
+        self.profiler_t0_ns = int(profiler_t0_ns)
         self._by_req: Dict[str, List[trace_mod.TraceEvent]] = {}
         for e in events:
             if e.event_type != trace_mod.SPAN:
@@ -298,7 +326,7 @@ class RequestTimeline:
 
     @classmethod
     def from_tracer(cls, tracer) -> "RequestTimeline":
-        return cls(tracer.events())
+        return cls(tracer.events(), tracer.profiler_t0_ns)
 
     def request_ids(self) -> List[str]:
         return sorted(self._by_req)
@@ -370,7 +398,10 @@ class RequestTimeline:
     def export_perfetto(self, path: str, pid: int = 1) -> None:
         """One track (tid) per request: X slices for the lifecycle
         segments (queued / prefill / decode / requeued), instants for
-        chunk ingests, verify ticks, preemptions and replays."""
+        chunk ingests, verify ticks, preemptions and replays.
+        ``otherData.profiler_offset_us`` added to a ``ts`` gives its time
+        on the profiler's host clock, to lay the tracks over a device
+        trace."""
         out: List[Dict[str, Any]] = [
             {"ph": "M", "name": "process_name", "pid": pid,
              "args": {"name": "requests"}}]
@@ -400,7 +431,9 @@ class RequestTimeline:
                                 "args": {"seq": e.packet_timestamp,
                                          "value": e.packet_data_id}})
         with open(path, "w") as f:
-            json.dump({"traceEvents": out, "displayTimeUnit": "ms"}, f)
+            json.dump({"traceEvents": out, "displayTimeUnit": "ms",
+                       "otherData": {"profiler_offset_us":
+                                     self.profiler_t0_ns / 1e3}}, f)
 
 
 # ---------------------------------------------------------------------------

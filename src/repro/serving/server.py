@@ -32,6 +32,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional
 
 import numpy as np
 
+from ..core import tracer as trace_mod
 from ..core.graph import Graph, OutputStreamPoller
 from ..core.metrics import MetricsRegistry
 from .batching import DeadlineExceeded
@@ -274,6 +275,9 @@ class GraphServer:
                 # NULL_OBSERVER is a shared singleton: never mutate it
                 obs.recorder = rec
             self._recorder = rec
+        # a `serve.deliver` span on the profiler's clock per token
+        # packet the pump thread hands to its handle
+        self._span = trace_mod.span_factory(not trace_mod.COMPILED_OUT)
         self._threads = [
             threading.Thread(target=self._pump_tokens, daemon=True,
                              name="graphserver-tokens"),
@@ -512,8 +516,11 @@ class GraphServer:
             self._fail_pending(e)
 
     def _dispatch_token(self, payload: Dict[str, Any]) -> None:
-        h = self._handle_of(payload["id"])
-        if h is not None:
+        with self._span("serve.deliver",
+                        tokens=int(payload["token"] is not None)):
+            h = self._handle_of(payload["id"])
+            if h is None:
+                return
             h._on_token(payload["token"], payload["finished"],
                         payload.get("finish_reason", ""),
                         payload.get("metrics"))

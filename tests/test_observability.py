@@ -488,20 +488,19 @@ class TestServerIntegration:
         assert {"serve.ttft_ms", "serve.itl_ms", "serve.queue_wait_ms",
                 "serve.decode_step_ms", "serve.batch_occupancy",
                 "serve.requests_submitted", "serve.requests_finished",
-                "serve.tokens_emitted", "engine.jit_compiles",
-                "engine.jit_compile_ms"} <= names
+                "serve.tokens_emitted", "engine.compiles",
+                "engine.compile_ms"} <= names
         assert ctotal(traced_run.snap["serve.requests_finished"]) == 3
         assert ctotal(traced_run.snap["serve.tokens_emitted"]) == 15
         assert hcount(traced_run.snap["serve.ttft_ms"]) == 3
 
     def test_engine_jit_labels(self, engine):
         reg = engine.metrics
-        c = reg.get("engine.jit_compiles")
+        c = reg.get("engine.compiles")
         assert c.total() >= 2                       # prefill + decode
-        assert c.value(step="serve_decode", layout="slot/0",
-                       width="") >= 1
-        hist = reg.get("engine.jit_compile_ms")
-        assert hist.quantile(0.5) is not None
+        assert c.value(fun="jit(slot_decode_step)") >= 1
+        hist = reg.get("engine.compile_ms")
+        assert hist.quantile(0.5, fun="jit(slot_decode_step)") is not None
 
     def test_prometheus_export_validates(self, traced_run, tmp_path):
         p = tmp_path / "server.prom"
