@@ -12,7 +12,10 @@ drawn from ``--seed``: the repository ships no checkpoint.
 * **serve** — ``LLMEngine(minicpm_2b, max_len=2048)`` in bf16 behind a
   paged ``GraphServer`` with 4 slots serves 8 requests (prompt lengths
   drawn from {256, 1024}, 32 new tokens each), compared per request with
-  ``engine.generate``, the repository's exactness oracle.
+  ``engine.generate``, the repository's exactness oracle.  Its decode
+  attention is the default paged-attention kernel: the lowered decode
+  step must hold it as a Mosaic ``tpu_custom_call``, and it must agree
+  with its float32 oracle on random inputs at the serving shapes.
 * **kernels** — the same requests through engines that share those
   params with the Pallas kernels on (flash prefill attention, fused
   RMSNorm, fused flash decode in its gathered and split-K variants),
@@ -86,7 +89,8 @@ MAX_CANDIDATES = 10
 KERNEL_REL_TOL = 2.0 ** -7
 KERNELS = {"flash": "_flash_kernel", "rmsnorm": "_rmsnorm_kernel",
            "fused": "_fused_gather_kernel",
-           "fused_split_k": "_fused_splitk_kernel"}
+           "fused_split_k": "_fused_splitk_kernel",
+           "paged": "_paged_kernel"}
 
 
 class CompileClock:
@@ -388,12 +392,11 @@ class Smoke:
             if not ok:
                 self.fail(f"{name}: kernel {k} is not a tpu_custom_call")
 
-    def decode_kernel_vs_oracle(self, name, split_k):
-        """The fused decode kernel against its f32 oracle on random bf16
-        inputs at the serving shapes: attention output and the arena
-        pages it scatters into."""
+    def decode_inputs(self):
+        """Random bf16 decode inputs at the serving shapes: q, new K/V,
+        the two arenas, block tables of distinct pages, and positions
+        spread over the tables' live length."""
         jax, jnp, np = self.jax, self.jnp, self.np
-        from repro.kernels import ops, ref
         cfg = self.cfg
         H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         pages = MAX_LEN // BLOCK
@@ -411,15 +414,11 @@ class Smoke:
         used = NUM_BLOCKS // SLOTS - 1
         tables[:, :used] = rows.reshape(SLOTS, used)
         positions = np.linspace(0, used * BLOCK - 1, SLOTS).astype(np.int32)
-        out = ops.fused_flash_decode(q, k_new, v_new, kp, vp, tables,
-                                     positions, rope_theta=cfg.rope_theta,
-                                     split_k=split_k)
-        f32 = [a.astype(jnp.float32) for a in (q, k_new, v_new, kp, vp)]
-        with jax.default_matmul_precision("highest"):
-            want = jax.jit(ref.fused_flash_decode_ref,
-                           static_argnames=("rope_theta",))(
-                *f32, tables, positions, rope_theta=cfg.rope_theta)
-        for part, got, exp in zip(("out", "k_pages", "v_pages"), out, want):
+        return q, k_new, v_new, kp, vp, tables, positions
+
+    def check_vs_oracle(self, name, parts, out, want):
+        np = self.np
+        for part, got, exp in zip(parts, out, want):
             got, exp = np.asarray(got, np.float32), np.asarray(exp)
             if part != "out":       # block 0 is the trash block
                 got, exp = got[1:], exp[1:]
@@ -429,6 +428,36 @@ class Smoke:
                   f"(bound {bound:.6f})", flush=True)
             if not err <= bound:
                 self.fail(f"{name}: {part} off its f32 oracle by {err}")
+
+    def paged_kernel_vs_oracle(self, name):
+        """The paged-attention kernel against its f32 oracle on random
+        bf16 inputs at the serving shapes."""
+        jax, jnp = self.jax, self.jnp
+        from repro.kernels import ops, ref
+        q, _, _, kp, vp, tables, positions = self.decode_inputs()
+        out = ops.paged_attention(q[:, 0], kp, vp, tables, positions)
+        f32 = [a.astype(jnp.float32) for a in (q[:, 0], kp, vp)]
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(ref.paged_attention_ref)(*f32, tables, positions)
+        self.check_vs_oracle(name, ("out",), (out,), (want,))
+
+    def decode_kernel_vs_oracle(self, name, split_k):
+        """The fused decode kernel against its f32 oracle on random bf16
+        inputs at the serving shapes: attention output and the arena
+        pages it scatters into."""
+        jax, jnp = self.jax, self.jnp
+        from repro.kernels import ops, ref
+        cfg = self.cfg
+        q, k_new, v_new, kp, vp, tables, positions = self.decode_inputs()
+        out = ops.fused_flash_decode(q, k_new, v_new, kp, vp, tables,
+                                     positions, rope_theta=cfg.rope_theta,
+                                     split_k=split_k)
+        f32 = [a.astype(jnp.float32) for a in (q, k_new, v_new, kp, vp)]
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(ref.fused_flash_decode_ref,
+                           static_argnames=("rope_theta",))(
+                *f32, tables, positions, rope_theta=cfg.rope_theta)
+        self.check_vs_oracle(name, ("out", "k_pages", "v_pages"), out, want)
 
     def phase_end(self, name, t0, c0):
         print(f"{name}: {time.perf_counter() - t0:.1f}s wall, "
@@ -444,6 +473,8 @@ class Smoke:
         self.reference(engine)
         t0, c0 = time.perf_counter(), self.clock.secs
         self.check_path("serve", engine)
+        self.kernels_compiled("serve", engine, ["paged"])
+        self.paged_kernel_vs_oracle("serve")
         self.phase_end("serve", t0, c0)
 
         for split_k in (False, True):

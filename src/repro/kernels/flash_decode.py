@@ -24,8 +24,8 @@ the serving hot path used to issue as separate XLA ops:
   ``repro.models.paging.slot_arena_tables``);
 * **split-K** — the default variant stages the whole row into VMEM
   scratch and runs one fully-gathered softmax (bit-exact against
-  ``repro.kernels.ref.fused_flash_decode_ref``, like
-  ``paged_attention.py``).  ``split_k=True`` switches to an
+  ``repro.kernels.ref.fused_flash_decode_ref``).  ``split_k=True``
+  switches to an
   online-softmax recurrence with per-page partial reductions (m/l/acc
   scratch) that *skips the attention math for pages past the last valid
   position* — work becomes proportional to the row's actual length
@@ -166,7 +166,8 @@ def _fused_gather_kernel(tables_ref, pos_ref, q_ref, kn_ref, vn_ref,
         k = k_scr[...].astype(jnp.float32)                # [T, KV, hd]
         v = v_scr[...].astype(jnp.float32)
         # same contraction and scale expression as the ref oracle
-        # (bit-exactness contract, see paged_attention.py)
+        # (bit-exactness contract: float(hd)**-0.5 rounds from float64
+        # and is 1 ulp off for non-power-of-two head dims)
         scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
         s = jax.lax.dot_general(
             qg, k, (((3,), (2,)), ((1,), (1,))),

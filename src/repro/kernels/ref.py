@@ -136,10 +136,10 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     positions: [B] int32 — current cache position; keys at index <= pos
         are attended (the position being written included).
 
-    This is the bit-exactness oracle for the Pallas kernel: per-batch-row
-    math uses the SAME op sequence (dot_general with KV batch dims,
-    explicit max/exp/sum softmax in fp32), so in interpret mode the
-    kernel must match bitwise, not just allclose.
+    This is the oracle for the Pallas paged-attention kernel: one
+    fully-gathered fp32 softmax per row, which the kernel's online
+    softmax over live pages matches to fp32 reduction order
+    (``tests/test_paged_attention.py``).
     """
     B, H, hd = q.shape
     bs, KV = k_pages.shape[1], k_pages.shape[2]

@@ -4,6 +4,7 @@ optional drop-in for the prefill/train path (see repro.kernels).
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -313,11 +314,13 @@ def _paged_decode(params, cfg: ArchConfig, q, k, v, cache, cache_pos,
     Each new token's K/V is scattered into the sequence's current tail
     block (``table[b, pos // bs]`` at offset ``pos % bs``); rows whose
     table entry is the trash block 0 (inactive slots, padding) write
-    harmlessly there.  Attention then either gathers pages back into
-    position order — which reconstructs exactly the contiguous cache row,
-    keeping greedy decode bit-identical to the ``cache_pos`` path — or
-    runs the Pallas paged-attention kernel (``flags.use_paged_kernel``)
-    that reads through the block table directly.
+    harmlessly there.  A single-token step then attends through
+    :func:`_paged_attention` (the Pallas paged-attention kernel, which
+    reads each row's live pages in place); a speculative window, and a
+    layer whose kv heads do not divide the decode shards, gathers pages
+    back into position order — which reconstructs exactly the
+    contiguous cache row, keeping greedy decode bit-identical to the
+    ``cache_pos`` path.
     """
     NB, bs, KV, hd = cache["k"].shape
     P = block_tables.shape[1]
@@ -358,19 +361,70 @@ def _paged_decode(params, cfg: ArchConfig, q, k, v, cache, cache_pos,
         blk, off = paging.tail_refs(block_tables, pos, bs)
         k_new = paging.scatter_token(cache["k"], blk, off, k[:, 0])
         v_new = paging.scatter_token(cache["v"], blk, off, v[:, 0])
-    if flags is not None and getattr(flags, "use_paged_kernel", False):
-        from ..kernels.ops import paged_attention
-        out = paged_attention(q[:, 0], k_new, v_new, block_tables,
-                              pos)[:, None]
+    if paging.use_paged_attention(cfg, flags):
+        out = _paged_attention(flags, q[:, 0], k_new, v_new, block_tables,
+                               pos)[:, None]
     else:
-        with jax.named_scope("gather"):
-            k_seq = paging.gather_pages(k_new, block_tables)
-            v_seq = paging.gather_pages(v_new, block_tables)
-        valid = paging.valid_mask(P * bs, pos)
-        mask = valid[:, None, None, None, :]         # [B,1,1,1,T]
-        out = _grouped_attention(q, k_seq, v_seq, mask)
+        out = _gathered_attention(q, k_new, v_new, block_tables, pos)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     return y, {"k": k_new, "v": v_new}
+
+
+def _gathered_attention(q, k_arena, v_arena, block_tables, pos):
+    """Single-token attention over each row's page-gathered sequence
+    (``[B, P*bs, KV, hd]``, masked past ``pos``): the contiguous slot
+    path's math on the same values, bit for bit."""
+    bs = k_arena.shape[1]
+    with jax.named_scope("gather"):
+        k_seq = paging.gather_pages(k_arena, block_tables)
+        v_seq = paging.gather_pages(v_arena, block_tables)
+    valid = paging.valid_mask(block_tables.shape[1] * bs, pos)
+    return _grouped_attention(q, k_seq, v_seq,
+                              valid[:, None, None, None, :])  # [B,1,1,1,T]
+
+
+def _paged_attention_cpu(q, k_arena, v_arena, block_tables, pos):
+    """:func:`_paged_attention` as lowered for the CPU: the page gather
+    in XLA.  It is the slot path's math bit for bit, which the CPU tests
+    pin paged serving to, and it spares every CPU serving test an
+    interpreted kernel; ``tests/test_paged_attention.py`` swaps the
+    interpreted kernel in for a served run."""
+    return _gathered_attention(q[:, None], k_arena, v_arena, block_tables,
+                               pos)[:, 0]
+
+
+def _paged_kernel_call(flags, q, k_arena, v_arena, block_tables, pos):
+    """The paged-attention kernel, single-device or tensor-parallel:
+    under a serving mesh it is shard_mapped over the model axis with
+    each rank's slice of the query and kv heads (the pattern of
+    :func:`_fused_decode_call`; ``paging.use_paged_attention`` admits
+    only kv heads that divide the mesh, so GQA groups stay rank-local),
+    block tables and positions replicated."""
+    from ..kernels.ops import paged_attention
+    mesh = getattr(flags, "decode_mesh", None) if flags is not None else None
+    shards = getattr(flags, "decode_shards", 1) if flags is not None else 1
+    if mesh is None or shards <= 1:
+        return paged_attention(q, k_arena, v_arena, block_tables, pos)
+    from jax.sharding import PartitionSpec as P
+    axis = getattr(flags, "model_axis", "model")
+    return jax.shard_map(
+        paged_attention, mesh=mesh,
+        in_specs=(P(None, axis, None), P(None, None, axis, None),
+                  P(None, None, axis, None), P(None, None), P(None)),
+        out_specs=P(None, axis, None), check_vma=False)(
+            q, k_arena, v_arena, block_tables, pos)
+
+
+def _paged_attention(flags, q, k_arena, v_arena, block_tables, pos):
+    """Single-token decode attention over a paged arena.  q: [B, H, hd];
+    returns [B, H, hd].  On a TPU it is the Pallas paged-attention
+    kernel (``kernels/paged_attention.py``), which reads only each
+    row's live pages, in place; lowered for the CPU it is
+    :func:`_paged_attention_cpu`."""
+    return jax.lax.platform_dependent(
+        q, k_arena, v_arena, block_tables, pos,
+        cpu=_paged_attention_cpu,
+        default=partial(_paged_kernel_call, flags))
 
 
 def prefill_extend_into_cache(params, cfg: ArchConfig, x: jax.Array,

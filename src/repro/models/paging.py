@@ -107,6 +107,21 @@ def use_fused_decode(cfg, flags) -> bool:
             and (shards == 1 or cfg.num_kv_heads % shards == 0))
 
 
+def use_paged_attention(cfg, flags) -> bool:
+    """Should a paged single-token decode step attend through the Pallas
+    paged-attention kernel (``kernels.paged_attention``)?
+
+    It is the default for every GQA/MHA/MQA layer on a paged arena
+    (MLA decodes its latent cache in ``mla.py``, and a paged arena has
+    no sliding window), asked only once the fused path has declined.
+    Under tensor-parallel serving the kernel runs under ``shard_map``
+    on each rank's kv heads, so the kv heads must divide the decode
+    shards; otherwise the page gather, which GSPMD partitions on its
+    own, attends.  Speculative windows always take the gather."""
+    shards = getattr(flags, "decode_shards", 1) if flags is not None else 1
+    return shards == 1 or cfg.num_kv_heads % shards == 0
+
+
 def fused_page_size(max_len: int, preferred: int = 8) -> int:
     """Page granularity for viewing a contiguous slot row as an arena.
 

@@ -42,11 +42,6 @@ class RuntimeFlags:
     moe_impl: str = "gather"
     model_axis: str = "model"
     model_size: int = 1
-    # Paged decode: read K/V through block tables with the Pallas
-    # paged-attention kernel instead of the pure-JAX page gather.
-    # GQA/MHA/MQA only — MLA's latent cache always uses the gather path
-    # (LLMEngine.new_cache rejects the combination).
-    use_paged_kernel: bool = False
     # Fused flash-decode: run the whole decode / speculative-verify
     # window (RoPE + tail-block KV scatter + per-query-masked attention)
     # as one Pallas call on every layout — slot rows are viewed as a
@@ -61,8 +56,9 @@ class RuntimeFlags:
     # Tensor-parallel SERVING (docs/SHARDING.md): set by LLMEngine when
     # it is built with a device mesh.  ``decode_shards`` is the model
     # axis size and ``decode_mesh`` the Mesh itself — the fused
-    # flash-decode dispatch shard_maps the kernel over it (per-rank K/V
-    # head slices, replicated block tables).  Distinct from
+    # flash-decode and paged-attention dispatches shard_map their
+    # kernels over it (per-rank K/V head slices, replicated block
+    # tables).  Distinct from
     # ``model_size``, the TRAINING sequence-parallel degree: serving
     # steps stay single-program per rank and never set model_size.
     decode_shards: int = 1

@@ -119,24 +119,30 @@ def make_decode_step(model: Model, flags: RuntimeFlags = DEFAULT_FLAGS):
     return decode_step
 
 
-def kernel_path(cfg, flags: RuntimeFlags, backend_kind: str = "slot") -> str:
+def kernel_path(cfg, flags: RuntimeFlags, backend_kind: str = "slot",
+                step: str = "decode") -> str:
     """Which decode-attention implementation a serving step will run:
-    ``"fused"`` (the fused flash-decode Pallas kernel) or ``"fallback"``
-    (page gather / masked contiguous attention).
+    ``"fused"`` (the fused flash-decode Pallas kernel), ``"paged"`` (the
+    paged-attention Pallas kernel: single-token decode on a paged or
+    hybrid arena) or ``"fallback"`` (page gather / masked contiguous
+    attention).
 
     The observability face of the dispatch seam: LLMEngine labels its
-    ``engine.kernel_path`` counter and per-step timing histograms with
-    this, so a silent fall-off the fast path (MLA, sliding window,
-    multi-host, a recurrent-only stack, or the flag simply unset) shows
-    up in ``metrics_text()`` instead of only in throughput."""
+    ``engine.kernel_path`` counter with this, so a silent fall-off the
+    kernels (MLA, sliding window, a speculative window on the gather,
+    a recurrent-only stack) shows up in ``metrics_text()`` instead of
+    only in throughput."""
     from ..models import paging
-    if not paging.use_fused_decode(cfg, flags):
-        return "fallback"
     if getattr(cfg, "use_mla", False):
         return "fallback"          # latent cache decodes in mla.py
     if "attn" not in cfg.layer_kinds():
         return "fallback"          # recurrent-only stack: nothing to fuse
-    return "fused"
+    if paging.use_fused_decode(cfg, flags):
+        return "fused"
+    if (backend_kind in ("paged", "hybrid") and step == "decode"
+            and paging.use_paged_attention(cfg, flags)):
+        return "paged"
+    return "fallback"
 
 
 def make_serve_decode_step(model: Model,

@@ -137,34 +137,56 @@ class LLMEngine:
         self._extend_steps: Dict[Tuple, Any] = {}
         self._verify_steps: Dict[Tuple, Any] = {}
         self._state_rewind = None       # built on first verify/truncate
-        # per-(step, layout) bound kernel-path counter (_observe_kernel
-        # runs on every decode tick; keep it off the registry lookup path)
+        # per-(step, layout) bound kernel-path and page counters
+        # (_observe_kernel runs on every decode tick; keep it off the
+        # registry lookup path)
         self._kernel_obs: Dict[Tuple, Any] = {}
 
     @staticmethod
     def _layout(backend) -> str:
         return f"{backend.kind}/{getattr(backend, 'block_size', 0)}"
 
-    def _observe_kernel(self, step: str, backend) -> None:
+    def _observe_kernel(self, step: str, backend, positions=None,
+                        active=None, block_tables=None) -> None:
         """Count which attention implementation served a decode/verify
-        step (``fused`` Pallas flash-decode vs the gather ``fallback``),
-        so a silent fall-off the fast path shows up in
-        ``metrics_text()``, not just as degraded throughput.  Runs on
-        every decode tick: the dispatch decision, label set and counter
-        handle are resolved once per (step, layout) and cached."""
+        step (``fused`` flash-decode, ``paged`` paged-attention kernel,
+        or the gather ``fallback``), so a silent fall-off the kernels
+        shows up in ``metrics_text()``, not just as degraded
+        throughput.  A ``paged`` decode step also adds to
+        ``engine.decode_pages``: ``kind="read"``, the live pages of its
+        active rows (what the kernel reads, besides one trash page per
+        inactive row), and ``kind="table"``, rows x table width (what a
+        page gather reads).  Runs on every decode tick: the dispatch
+        decision, label sets and counter handles are resolved once per
+        (step, layout) and cached."""
         if not self.metrics.enabled:
             return
         key = (step, backend.kind, getattr(backend, "block_size", 0))
-        ctr = self._kernel_obs.get(key)
-        if ctr is None:
+        obs = self._kernel_obs.get(key)
+        if obs is None:
+            path = kernel_path(self.cfg, self.flags, backend.kind, step)
             ctr = self.metrics.counter(
                 "engine.kernel_path",
                 "decode/verify steps by attention implementation "
-                "(fused flash-decode kernel vs gather fallback)"
-            ).bind(path=kernel_path(self.cfg, self.flags, backend.kind),
-                   step=step, layout=self._layout(backend))
-            self._kernel_obs[key] = ctr
+                "(fused flash-decode, paged-attention kernel, gather "
+                "fallback)"
+            ).bind(path=path, step=step, layout=self._layout(backend))
+            pages = None
+            if path == "paged" and step == "decode":
+                c = self.metrics.counter(
+                    "engine.decode_pages",
+                    "paged decode steps' pages: live pages of the active "
+                    "rows (read) and rows x table width (table)")
+                pages = (c.bind(kind="read"), c.bind(kind="table"))
+            obs = self._kernel_obs[key] = (ctr, pages)
+        ctr, pages = obs
         ctr.inc()
+        if pages is not None:
+            rows, width = np.shape(block_tables)
+            live = (np.asarray(positions)[np.asarray(active, bool)]
+                    // backend.block_size + 1)
+            pages[0].inc(int(np.minimum(live, width).sum()))
+            pages[1].inc(rows * width)
 
     # ------------------------------------------------------------------
     # static-batch generation
@@ -238,11 +260,6 @@ class LLMEngine:
         if self.max_len % block_size != 0:
             raise ValueError(f"engine max_len {self.max_len} must be a "
                              f"multiple of block_size {block_size}")
-        if self.cfg.use_mla and getattr(self.flags, "use_paged_kernel",
-                                        False):
-            raise ValueError("use_paged_kernel covers GQA/MHA/MQA only; "
-                             "MLA paged decode uses the latent-gather "
-                             "path (drop the flag)")
         self.check_extend_support("paged")
 
     def _check_hybrid(self, block_size: int) -> None:
@@ -250,11 +267,6 @@ class LLMEngine:
         if self.max_len % block_size != 0:
             raise ValueError(f"engine max_len {self.max_len} must be a "
                              f"multiple of block_size {block_size}")
-        if self.cfg.use_mla and getattr(self.flags, "use_paged_kernel",
-                                        False):
-            raise ValueError("use_paged_kernel covers GQA/MHA/MQA only; "
-                             "MLA paged decode uses the latent-gather "
-                             "path (drop the flag)")
         self.check_extend_support("hybrid")
 
     def check_extend_support(self, backend_kind: str = "slot") -> None:
@@ -286,21 +298,15 @@ class LLMEngine:
         pass with state stacks + rewind (docs/STATE_CACHE.md).  Neither
         has a sliding-window mask.  Verify windows run in-kernel under
         ``use_fused_decode`` (the fused flash-decode kernel masks each
-        query at ``idx <= pos + s``); the older single-query
-        ``use_paged_kernel`` cannot express a window, so on its own it
-        still forces the page-gather fallback and is rejected."""
+        query at ``idx <= pos + s``) and through the page gather
+        otherwise: the single-query paged-attention kernel serves plain
+        decode steps only."""
         if backend_kind in STATE_KINDS:
             if self.cfg.sliding_window and "attn" in self.cfg.layer_kinds():
                 raise ValueError("speculative decode has no "
                                  "sliding-window mask")
         else:
             check_paged_support(self.cfg)
-        if (getattr(self.flags, "use_paged_kernel", False)
-                and not getattr(self.flags, "use_fused_decode", False)):
-            raise ValueError("speculative decode reads paged K/V through "
-                             "the page-gather path; drop use_paged_kernel "
-                             "(the single-query Pallas kernel cannot "
-                             "verify a window — use use_fused_decode)")
         if getattr(self.flags, "model_size", 1) > 1:
             raise ValueError("speculative decode is single-host for now")
 
@@ -431,7 +437,8 @@ class LLMEngine:
             next_tok, cache = step(*args)
             with span("engine.decode.sync"):
                 out = np.asarray(next_tok[:, 0])
-        self._observe_kernel("decode", backend)
+        self._observe_kernel("decode", backend, positions, active,
+                             block_tables)
         return out, cache
 
     def verify(self, backend, cache, tokens: np.ndarray,

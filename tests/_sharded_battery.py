@@ -15,7 +15,8 @@ the unsharded run — sharding is a memory layout, never a semantic
 (docs/SHARDING.md).  Covered: plain decode, speculative verify windows,
 chunked prefill, preemption + replay, and the capacity scaling of the
 default paged arena — across slot | paged | state | hybrid backends and
-the fused | unfused decode dispatch.
+the fused | unfused decode dispatch, and the paged-attention kernel's
+head-parallel dispatch.
 
 Prints one ``BATTERY {json}`` line: {scenario: {ok, detail}}.
 """
@@ -60,9 +61,11 @@ HYBRID = dataclasses.replace(
 _ENGINES = {}
 
 
-def engine_for(cfg, fused, tp):
-    """One engine per (config, fused, mesh-size); tp=0 is unsharded."""
-    key = (cfg.name, cfg.vocab_size, fused, tp)
+def engine_for(cfg, fused, tp, tag=""):
+    """One engine per (config, fused, mesh-size, tag); tp=0 is
+    unsharded.  A tag keeps apart engines whose steps are traced under
+    a different dispatch."""
+    key = (cfg.name, cfg.vocab_size, fused, tp, tag)
     if key not in _ENGINES:
         flags = dataclasses.replace(DEFAULT_FLAGS, use_fused_decode=True) \
             if fused else None
@@ -115,6 +118,26 @@ def main():
             tag = "fused" if fused else "unfused"
             record(f"decode/{backend}/{tag}/tp{tp}", outs == base,
                    "" if outs == base else f"{outs} != {base}")
+
+    # ---- the paged-attention kernel's own dispatch ------------------
+    # Lowered for the CPU, paged decode attention is the page gather;
+    # here it goes through the kernel's dispatch (interpreted; shard_map
+    # over each rank's kv heads on a mesh), unsharded run included.
+    from repro.models import attention
+    gather_dispatch = attention._paged_attention
+    attention._paged_attention = attention._paged_kernel_call
+    try:
+        prompts = prompts_for(ATTN)
+        srv_kw = {"backend": "paged", "block_size": 8}
+        base, _ = serve(engine_for(ATTN, False, 0, "kernel"), prompts,
+                        **srv_kw)
+        for tp in MESH_SIZES:
+            outs, _ = serve(engine_for(ATTN, False, tp, "kernel"), prompts,
+                            **srv_kw)
+            record(f"decode/paged/kernel/tp{tp}", outs == base,
+                   "" if outs == base else f"{outs} != {base}")
+    finally:
+        attention._paged_attention = gather_dispatch
 
     # ---- verify windows: speculative decode on the loop workload -----
     for backend in ("slot", "paged"):

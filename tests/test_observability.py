@@ -502,6 +502,31 @@ class TestServerIntegration:
         hist = reg.get("engine.compile_ms")
         assert hist.quantile(0.5, fun="jit(slot_decode_step)") is not None
 
+    def test_paged_decode_pages_counter(self):
+        """A paged decode step is labelled path="paged", and
+        ``engine.decode_pages`` adds the active rows' live pages (read)
+        and rows x table width (table): two rows of 5 and 12 prompt
+        tokens, 8-token pages, 4-page tables, five decode steps at
+        positions 5..9 and 12..16."""
+        from repro.serving import PagedBackend
+        eng = LLMEngine(small_cfg(), max_len=32, seed=7)
+        sched = Scheduler(PagedBackend(eng, 2, num_blocks=9, block_size=8),
+                          max_new_tokens=6)
+        rng = np.random.RandomState(8)
+        for i, n in enumerate((5, 12)):
+            sched.submit({"tokens": rng.randint(0, 512, size=n).astype(
+                np.int32), "id": i})
+        while sched.has_work():
+            sched.admit()
+            sched.step()
+        reg = eng.metrics
+        assert reg.get("engine.kernel_path").value(
+            path="paged", step="decode", layout="paged/8") == 5
+        pages = reg.get("engine.decode_pages")
+        # row 0: 1+1+1+2+2 live pages; row 1: 2+2+2+2+3
+        assert pages.value(kind="read") == 7 + 11
+        assert pages.value(kind="table") == 5 * 2 * 4
+
     def test_prometheus_export_validates(self, traced_run, tmp_path):
         p = tmp_path / "server.prom"
         p.write_text(traced_run.text)

@@ -194,7 +194,10 @@ def test_decode_hlo_carries_layer_kind_scopes():
                       engine.new_cache(backend), jnp.zeros(n, jnp.int32),
                       jnp.ones(n, bool),
                       jnp.zeros((n, 8), jnp.int32)).compile().as_text()
-    paths = set(re.findall(r'op_name="([^"]+)"', text))
+    # the per-platform dispatch of paged decode attention adds
+    # ``cond/branch_<n>_fun`` components, which are no scope
+    paths = {re.sub(r"cond/branch_\d+_fun/", "", p)
+             for p in re.findall(r'op_name="([^"]+)"', text)}
     for scope in ("attn/gather/", "attn/kv_write/", "/ffn/", "/cache/",
                   "/norm/", "/embed/", "/head/"):
         assert any(scope in p for p in paths), scope

@@ -64,6 +64,14 @@ def test_decode_bit_identical_fused(battery, backend, tp):
     _check(battery, f"decode/{backend}/fused/tp{tp}")
 
 
+@pytest.mark.parametrize("tp", [1, 2, 4])
+def test_decode_bit_identical_paged_kernel(battery, tp):
+    """The paged-attention kernel's dispatch (interpreted; shard_map
+    over each rank's kv heads) streams the unsharded kernel run's exact
+    tokens."""
+    _check(battery, f"decode/paged/kernel/tp{tp}")
+
+
 @pytest.mark.parametrize("tp", [2, 4])
 @pytest.mark.parametrize("backend,tag", [("slot", "unfused"),
                                          ("paged", "unfused"),

@@ -1,5 +1,6 @@
 """Compile the main-path Pallas kernels for a TPU v5e chip, at MiniCPM-2B
-widths (36 heads x 64, d_model 2304), with no chip attached.
+widths (36 heads x 64, d_model 2304; the paged-attention kernel also
+at DeepSeek-7B's 32 x 128), with no chip attached.
 
 Every other kernel test runs the kernels in interpret mode.  These
 compile them through Mosaic for a described v5e topology, the way the
@@ -95,6 +96,28 @@ def test_fused_flash_decode(one_chip, split_k):
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes >= 2 * num_blocks * bs * KV_HEADS \
         * HEAD_DIM * 2
+
+
+@pytest.mark.parametrize("rows,pages,kv_heads,head_dim", [
+    (11, 128, 36, 64), (2, 256, 32, 128)],
+    ids=["minicpm-2b.chat", "deepseek-7b-pp2.longprompt"])
+def test_paged_attention(one_chip, rows, pages, kv_heads, head_dim):
+    """The paged-attention kernel at the widths of the two benchmark
+    cells: 16-token pages, every row's table full width.  It asks for
+    no VMEM beyond Mosaic's default scoped limit, so compiling shows
+    its buffers and temporaries fit that limit; its own estimate of the
+    page buffers agrees."""
+    from repro.kernels.paged_attention import (DEFAULT_VMEM_LIMIT,
+                                               paged_vmem_bytes)
+    bs = 16
+    assert paged_vmem_bytes(bs, kv_heads, head_dim, 2, pages) \
+        <= DEFAULT_VMEM_LIMIT // 2
+    num_blocks = 1 + rows * pages
+    arena = _sds(one_chip, num_blocks, bs, kv_heads, head_dim)
+    _compile(ops.paged_attention,
+             _sds(one_chip, rows, kv_heads, head_dim), arena, arena,
+             _sds(one_chip, rows, pages, dtype=jnp.int32),
+             _sds(one_chip, rows, dtype=jnp.int32))
 
 
 def test_rmsnorm(one_chip):
